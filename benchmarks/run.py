@@ -12,6 +12,8 @@ from __future__ import annotations
 import sys
 import time
 
+from repro.compile_cache import enable_compile_cache
+
 from . import kernel_bench, paper_figures, roofline_table
 
 
@@ -241,6 +243,7 @@ _SMOKE = ("churn-smoke", "obs-smoke")
 
 
 def main() -> None:
+    enable_compile_cache()
     # the -smoke names are CI-scale variants: they would overwrite their
     # BENCH_*.json with test-scale numbers, so only run them by name
     names = sys.argv[1:] or [n for n in BENCHES if n not in _SMOKE]

@@ -29,6 +29,7 @@ import time
 import numpy as np
 
 from repro.core import embed, power, topology, vsr
+from repro.compile_cache import enable_compile_cache
 
 
 def main(quick: bool = False) -> None:
@@ -72,4 +73,5 @@ def main(quick: bool = False) -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main(quick="--quick" in sys.argv[1:])

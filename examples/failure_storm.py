@@ -30,6 +30,9 @@ from repro.core import dynamic, topology, vsr
 from repro.fault.monitor import PlacementMonitor
 from repro.telemetry import (Telemetry, load_events, render,
                              summarize_events)
+from repro.compile_cache import enable_compile_cache
+
+enable_compile_cache()
 
 QUICK = "--quick" in sys.argv
 SEED = 0

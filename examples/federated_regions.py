@@ -36,6 +36,7 @@ import numpy as np
 from repro.api import FederatedSession, PlacementSpec
 from repro.core import federation, topology, vsr
 from repro.fault.monitor import PlacementMonitor
+from repro.compile_cache import enable_compile_cache
 
 
 def main(quick: bool = False) -> None:
@@ -110,4 +111,5 @@ def main(quick: bool = False) -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main(quick="--quick" in sys.argv[1:])

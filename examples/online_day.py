@@ -29,6 +29,9 @@ from repro.api import CFNSession, PlacementSpec
 from repro.core import dynamic, topology
 from repro.telemetry import (Telemetry, load_events, render,
                              summarize_events)
+from repro.compile_cache import enable_compile_cache
+
+enable_compile_cache()
 
 SEED = 0
 SCENARIO = dynamic.SCENARIOS["diurnal24"]

@@ -16,6 +16,9 @@ from repro.models import model as M
 from repro.serve import cache as C
 from repro.serve import engine
 from repro.serve.scheduler import EnergyAwareScheduler, Service
+from repro.compile_cache import enable_compile_cache
+
+enable_compile_cache()
 
 # --- 1. serve a batch of requests through a real (reduced) model ----------
 cfg = configs.get_smoke("qwen3-4b")

@@ -9,6 +9,9 @@ CDC / AF / MF baselines (paper Fig. 3/4).
 """
 from repro.api import CFNSession, PlacementSpec
 from repro.core import topology, vsr
+from repro.compile_cache import enable_compile_cache
+
+enable_compile_cache()
 
 # 1. the paper's substrate: 20 RPi-class IoT devices in 4 Wi-Fi zones,
 #    one Access-Fog and one Metro-Fog server, a Xeon CDC behind the core

@@ -17,6 +17,7 @@ from repro.fault.runner import ResilientTrainer
 from repro.models import costs
 from repro.optim import adamw
 from repro.train.step import init_state, make_train_step
+from repro.compile_cache import enable_compile_cache
 
 
 def main():
@@ -49,4 +50,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -47,6 +47,7 @@ from .power import (PENALTY, PlacementAux, PlacementProblem, PlacementState,
                     init_state, objective, objective_batch, _commit_entries,
                     _delta_objective, _move_core)
 from .topology import CFNTopology
+from ..kernels import placement_power as kpp
 
 
 @dataclass
@@ -427,6 +428,18 @@ def _anneal_proposals(key: jax.Array, aux: PlacementAux, n_steps: int,
     return fi, p_prop, u
 
 
+def auto_backend(platform: str, P: int, N: int, J: int, D: int,
+                 T: int) -> str:
+    """The anneal backend ``"auto"`` resolves to: the fused Pallas kernel on
+    a TPU when a problem of P processing nodes, N network nodes, J VMs and
+    incidence degree D, annealed for T steps, fits the kernel's VMEM
+    (``kpp.fused_fits``), else the delta scan.  Both are compiled device
+    code; this is a size rule."""
+    if platform == "tpu" and kpp.fused_fits(P, N, J, D, T):
+        return "fused"
+    return "delta"
+
+
 def anneal(problem: PlacementProblem, key: jax.Array, X0: np.ndarray,
            n_chains: int = 32, n_steps: int = 4000,
            t0: float = 50.0, t1: float = 0.05,
@@ -443,7 +456,9 @@ def anneal(problem: PlacementProblem, key: jax.Array, X0: np.ndarray,
         fused across all steps in ONE kernel launch.
       * ``"full"``  -- legacy full `objective_batch` per step (kept as the
         benchmark baseline).
-      * ``"auto"``  -- fused on TPU, delta elsewhere.
+      * ``"auto"``  -- ``auto_backend``: fused on a TPU where the kernel
+        fits its VMEM (``kernels.placement_power.fused_fits``), delta
+        elsewhere.
 
     ``eligible`` [R, P] (optional) restricts each service row's destination
     nodes: the warm start is projected onto the mask, restart chains are
@@ -459,11 +474,12 @@ def anneal(problem: PlacementProblem, key: jax.Array, X0: np.ndarray,
     Pallas backend (chain state stays in VMEM).
     """
     R, V, P = problem.R, problem.V, problem.P
+    aux = build_aux(problem)
     if backend == "auto":
-        backend = "fused" if jax.default_backend() == "tpu" else "delta"
+        backend = auto_backend(jax.default_backend(), P, problem.N, R * V,
+                               aux.inc_h.shape[1], n_steps)
     if backend not in ("delta", "fused", "full"):
         raise ValueError(f"unknown anneal backend {backend!r}")
-    aux = build_aux(problem)
     if aux.free_pos.shape[0] == 0:
         # every VM is pinned (e.g. single-VM VSRs): nothing to anneal
         return _result(problem, jnp.asarray(X0, jnp.int32), "anneal")

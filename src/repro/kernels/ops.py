@@ -1,8 +1,8 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-On this CPU container the kernels execute in interpret mode (the kernel
-body runs as Python/jnp on CPU); on a real TPU set ``interpret=False`` (the
-default flips automatically based on the backend).
+On a TPU the kernels compile through Mosaic; on any other backend they run
+in Pallas interpret mode (the kernel body as jnp ops).  ``interpret=None``
+picks by ``jax.default_backend()``.
 """
 from __future__ import annotations
 
@@ -68,8 +68,8 @@ def fused_anneal(problem: PlacementProblem, aux, Xc: jax.Array,
     Returns (best_X [C, R, V], stats [C, 2] = (best obj, final obj)).
     Chain state (placement + live load tensors) stays resident in VMEM
     across all T steps -- no per-step objective launch.  Initial loads are
-    one batched evaluation out here; the kernel only ever touches the
-    compact [P*P, K] route table.
+    one batched evaluation out here.  Raises ``ValueError`` when the
+    substrate is past ``pp.fused_fits``.
     """
     interpret = _default_interpret() if interpret is None else interpret
     C, R, V = Xc.shape
@@ -77,10 +77,10 @@ def fused_anneal(problem: PlacementProblem, aux, Xc: jax.Array,
         p_prop = pp.mask_proposals(j_prop, p_prop, eligible, V)
     Xflat = Xc.reshape(C, -1).astype(jnp.int32)
     omega0, theta0, lam0, obj0 = batched_hard_loads(problem, Xc)
-    (_, _, F, _, route_flat, proc_params, net_params) = \
+    (_, _, F, _, route_inc, proc_params, net_params) = \
         pp.pack_problem(problem)
     bX, stats = pp.fused_anneal_tpu(
         Xflat, j_prop.astype(jnp.int32), p_prop.astype(jnp.int32), u_prop,
         temps, *pp.pack_aux(aux), omega0, theta0, lam0, obj0,
-        F, route_flat, proc_params, net_params, interpret=interpret)
+        F, route_inc, proc_params, net_params, interpret=interpret)
     return bX.reshape(C, R, V), stats

@@ -13,12 +13,8 @@ import jax
 
 
 def _make_mesh(shape, axes):
-    # jax.sharding.AxisType landed after 0.4.x; older jax defaults to Auto.
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(tuple(shape), tuple(axes))
     return jax.make_mesh(tuple(shape), tuple(axes),
-                         axis_types=(axis_type.Auto,) * len(axes))
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

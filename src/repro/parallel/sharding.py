@@ -28,20 +28,11 @@ Axis = Union[str, None]
 
 
 def shard_map_compat(f, mesh, in_specs, out_specs, axis_names, check=False):
-    """``jax.shard_map`` across jax versions.
-
-    Newer jax exposes top-level ``jax.shard_map(..., axis_names=...,
-    check_vma=...)``; 0.4.x has ``jax.experimental.shard_map.shard_map``
-    where the manual axes are instead the complement of ``auto`` and the
-    flag is ``check_rep``."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  axis_names=frozenset(axis_names), check_vma=check)
-    from jax.experimental.shard_map import shard_map as sm
-    auto = frozenset(mesh.axis_names) - frozenset(axis_names)
-    return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-              auto=auto, check_rep=check)
+    """``jax.shard_map`` manual over ``axis_names`` only (the other mesh
+    axes stay automatic)."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs,
+                         axis_names=frozenset(axis_names), check_vma=check)
 
 DEFAULT_RULES: Dict[str, Tuple[str, ...]] = {
     "batch": ("pod", "data"),

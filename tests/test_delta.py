@@ -13,6 +13,7 @@ import pytest
 
 from repro.core import power, solvers, topology, vsr
 from repro.kernels import ops, ref
+from repro.kernels import placement_power as pp
 
 
 @pytest.fixture(scope="module")
@@ -196,6 +197,46 @@ def test_fused_anneal_chain_padding(topo):
     exact = np.array([float(power.objective(prob, bX[c])) for c in range(C)])
     np.testing.assert_allclose(np.asarray(stats[:, 0]), exact,
                                rtol=1e-5, atol=5e-2)
+
+
+def test_route_incidence_matches_dense_paths(topo):
+    """The kernels' [P*P, N] route table is the dense path incidence of
+    the CSR routes."""
+    prob = _problem(topo)
+    got = pp.route_incidence(prob.route_idx, prob.N)
+    want = topo.dense_path_nodes().reshape(prob.P * prob.P, prob.N)
+    np.testing.assert_array_equal(np.asarray(got), want)
+
+
+def test_auto_backend_size_rule():
+    """``anneal(backend="auto")`` picks the fused kernel on a TPU exactly
+    where ``fused_fits`` admits the problem, and the delta scan
+    everywhere else."""
+    pick = solvers.auto_backend
+    assert pick("tpu", 23, 13, 30, 2, 4000) == "fused"
+    assert pick("tpu", 252, 86, 3072, 2, 4000) == "fused"
+    assert pick("tpu", 256, 128, 3072, 2, 12000) == "fused"
+    assert pick("tpu", 256, 128, 8192, 8, pp.FUSED_MAX_T) == "fused"
+    assert pick("tpu", 256, 128, 4096, 16, pp.FUSED_MAX_T) == "fused"
+    assert pick("tpu", 257, 128, 3072, 2, 4000) == "delta"
+    assert pick("tpu", 468, 126, 3072, 2, 4000) == "delta"
+    assert pick("tpu", 23, 13, 2 * pp.FUSED_MAX_J, 1, 4000) == "delta"
+    assert pick("tpu", 23, 13, 30, 2 * pp.FUSED_MAX_D, 4000) == "delta"
+    assert pick("tpu", 23, 13, 8192, 16, 4000) == "delta"     # J*D
+    assert pick("tpu", 23, 13, 30, 2, pp.FUSED_MAX_T + 1) == "delta"
+    assert pick("cpu", 23, 13, 30, 2, 4000) == "delta"
+
+
+def test_fused_backend_refuses_oversized_substrate():
+    """An explicit ``backend="fused"`` past the fit rule is a clear
+    ValueError before anything is lowered, not a compiler failure."""
+    big = topology.city_scale(n_olt=16, onus_per_olt=4, iot_per_onu=7)
+    prob = power.build_problem(big, vsr.random_vsrs(2, rng=0,
+                                                    source_nodes=[0]))
+    X0 = np.zeros((prob.R, prob.V), np.int32)
+    with pytest.raises(ValueError, match="fused_fits"):
+        solvers.anneal(prob, jax.random.PRNGKey(0), X0, n_chains=2,
+                       n_steps=8, backend="fused")
 
 
 def test_anneal_only_moves_free_positions(topo):

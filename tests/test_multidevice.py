@@ -99,10 +99,6 @@ SCRIPT = textwrap.dedent("""
 
 
 @pytest.mark.slow
-@pytest.mark.skipif(
-    not hasattr(__import__("jax"), "shard_map"),
-    reason="512-device dry-run needs jax>=0.5 shard_map semantics (jax 0.4.x"
-           " jaxlib fails an IsManualSubgroup check on these shardings)")
 def test_multidevice_semantics(tmp_path):
     script = tmp_path / "md.py"
     script.write_text(SCRIPT)
