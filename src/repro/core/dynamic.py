@@ -24,7 +24,6 @@ from __future__ import annotations
 import functools
 import heapq
 import warnings
-from contextlib import nullcontext
 from dataclasses import dataclass, field
 from typing import Callable, Dict, Iterable, Iterator, List, Optional, \
     Sequence, Tuple, Union
@@ -35,6 +34,7 @@ import numpy as np
 from . import embed as embed_mod
 from . import power, solvers, vsr
 from .topology import CFNTopology
+from ..telemetry import span
 
 
 # ---------------------------------------------------------------------------
@@ -391,16 +391,13 @@ def _bucket_rows(n: int, lo: int = 2) -> int:
 
 
 def _traced(name: str):
-    """Wrap an engine entry point in a telemetry span (no-op -- not even a
-    context manager allocation -- when no ``Telemetry`` is attached, so
-    the disabled path stays bit-identical and free)."""
+    """Wrap an engine entry point in a telemetry span (``span``: a shared
+    no-op context when no ``Telemetry`` is attached, so the disabled path
+    stays bit-identical and allocates nothing)."""
     def deco(fn):
         @functools.wraps(fn)
         def wrapper(self, *args, **kwargs):
-            tel = self.telemetry
-            if tel is None:
-                return fn(self, *args, **kwargs)
-            with tel.span(name):
+            with span(self.telemetry, name):
                 return fn(self, *args, **kwargs)
         return wrapper
     return deco
@@ -571,10 +568,6 @@ class OnlineEmbedder:
                 tel.ledger.set_tiers(tiers_of(self.topo))
             tel.attach_traces()
 
-    def _span(self, name: str, **attrs):
-        tel = self.telemetry
-        return nullcontext() if tel is None else tel.span(name, **attrs)
-
     def clone(self) -> "OnlineEmbedder":
         """A detached copy sharing the (immutable) arrays: events applied to
         the clone leave this engine untouched.  Used by benchmarks to replay
@@ -654,16 +647,19 @@ class OnlineEmbedder:
             self._problem = h.degrade(self._problem)
 
     def _resolve_kw(self, base: dict) -> dict:
-        """Per-event solver kwargs: bucket-stable sweep padding, plus
-        convergence-trace recording when telemetry wants it (host-side
-        materialization only -- the jitted scans always compute the
-        trace, so this flag can never retrace)."""
+        """Per-event solver kwargs: bucket-stable sweep padding, plus the
+        attached telemetry (the solver's phase spans) and convergence-trace
+        recording when telemetry wants it (host-side materialization only
+        -- the jitted scans always compute the trace, so this flag can
+        never retrace)."""
         kw = dict(base)
         if self.bucket_rows and self._problem is not None:
             kw["pad_positions_to"] = int(
                 self._problem.R * (self._problem.V - 1))
-        if self.telemetry is not None and self.telemetry.convergence:
-            kw["record_conv"] = True
+        if self.telemetry is not None:
+            kw["telemetry"] = self.telemetry
+            if self.telemetry.convergence:
+                kw["record_conv"] = True
         return kw
 
     def _drop_row(self, row: int) -> None:
@@ -710,6 +706,13 @@ class OnlineEmbedder:
     def _full_solve(self, event: str,
                     incumbent: Optional[solvers.SolveResult] = None
                     ) -> solvers.SolveResult:
+        """``_repack`` and commit its result."""
+        res = self._repack(incumbent)
+        self._commit(res, event)
+        return res
+
+    def _repack(self, incumbent: Optional[solvers.SolveResult] = None
+                ) -> solvers.SolveResult:
         """Spec-driven full solve (``spec.method``, ``spec.masks`` applied
         -- a defrag can no longer move a hop-constrained service out of its
         radius); an ``incumbent`` result for the SAME problem (the
@@ -717,14 +720,14 @@ class OnlineEmbedder:
         is kept when the portfolio fails to beat it, so defrags never
         regress."""
         res = embed_mod._embed(self.topo, self._batch_cache, self.spec,
-                               key=self._split_key(), problem=self._problem)
+                               key=self._split_key(), problem=self._problem,
+                               telemetry=self.telemetry)
         if incumbent is not None and incumbent.objective < res.objective:
             res = solvers.SolveResult(
                 X=incumbent.X, breakdown=incumbent.breakdown,
                 method=f"defrag-kept({incumbent.method})",
                 history=incumbent.history)
         self._events_since_defrag = 0
-        self._commit(res, event)
         return res
 
     def _carry_loads(self) -> Optional[tuple]:
@@ -1139,6 +1142,7 @@ class OnlineEmbedder:
         if not arr and not deps:
             wr.result = self._result
             return self._wave_deferred(wr, deferred)
+        tel = self.telemetry
         prev = (self._vsrs[:], self._sids[:], self._prio[:],
                 self._batch_cache, self._problem, self._X, self._state,
                 self._result, self._events_since_defrag)
@@ -1149,67 +1153,74 @@ class OnlineEmbedder:
         # double-counted inside a wave)
         dep_rows = sorted(self._sids.index(s) for s in deps)
         if dep_rows:
-            state = power.detach_vsrs(self._problem, state, dep_rows)
-            for r in sorted(dep_rows, reverse=True):
-                del self._vsrs[r]
-                del self._sids[r]
-                del self._prio[r]
-                self._drop_row(r)
+            with span(tel, "wave.detach"):
+                state = power.detach_vsrs(self._problem, state, dep_rows)
+                for r in sorted(dep_rows, reverse=True):
+                    del self._vsrs[r]
+                    del self._sids[r]
+                    del self._prio[r]
+                    self._drop_row(r)
         surv = [i for i in range(n0) if i not in set(dep_rows)]
         # phase 2: arrivals join the batch in one pass
-        for svc, sid, prio in arr:
-            self._vsrs.append(svc)
-            self._sids.append(sid)
-            self._prio.append(prio)
-            self._batch_cache = (svc if self._batch_cache is None
-                                 else self._batch_cache.concat(svc))
+        with span(tel, "wave.rebuild"):
+            for svc, sid, prio in arr:
+                self._vsrs.append(svc)
+                self._sids.append(sid)
+                self._prio.append(prio)
+                self._batch_cache = (svc if self._batch_cache is None
+                                     else self._batch_cache.concat(svc))
+            if self._vsrs:
+                self._rebuild_problem()
         if not self._vsrs:
             self._problem = self._X = self._state = self._result = None
             self._batch_cache = None
             self.stats.append(OnlineStats("wave", "empty", 0.0, 0.0, 0))
             wr.result = None
-            self._drain_queue()
+            with span(tel, "wave.drain"):
+                self._drain_queue()
             return self._wave_deferred(wr, deferred)
-        self._rebuild_problem()
         self._events_since_defrag += len(arr) + len(dep_rows)
         new_rows = list(range(len(surv), self.n_live))
         row_map = surv + [-1] * (self._problem.R - len(surv))
-        if prev_X is None:
-            # cold wave: start every arrival at its pinned source (the
-            # targeted sweeps re-place them; mirrors add-under-admission)
-            st = power.init_state(self._problem,
-                                  np.asarray(self._problem.fixed_node))
-            prev_power, prev_viol = 0.0, 0.0
-        else:
-            st = power.warm_state(
-                self._problem, prev_X,
-                prev_loads=(state.omega, state.tm, state.theta, state.lam),
-                row_map=row_map)
-            prev_power = 0.0 if prev[7] is None else prev[7].power
-            prev_viol = (0.0 if prev[7] is None
-                         else float(prev[7].breakdown.violation))
+        with span(tel, "wave.warm_state"):
+            if prev_X is None:
+                # cold wave: start every arrival at its pinned source (the
+                # targeted sweeps re-place them; mirrors
+                # add-under-admission)
+                st = power.init_state(self._problem,
+                                      np.asarray(self._problem.fixed_node))
+                prev_power, prev_viol = 0.0, 0.0
+            else:
+                st = power.warm_state(
+                    self._problem, prev_X,
+                    prev_loads=(state.omega, state.tm, state.theta,
+                                state.lam),
+                    row_map=row_map)
+                prev_power = 0.0 if prev[7] is None else prev[7].power
+                prev_viol = (0.0 if prev[7] is None
+                             else float(prev[7].breakdown.violation))
         # phase 3: ONE batched re-solve for the whole wave
         kw = self._add_kw if new_rows else self._remove_kw
         wave_bucket = 0
-        if self.telemetry is not None and new_rows:
+        if tel is not None and new_rows:
             n_pos = int((~np.asarray(
                 self._problem.fixed_mask)[new_rows]).sum())
             wave_bucket = solvers._pow2(n_pos) if n_pos else 0
-        with self._span("resolve_wave", n_arrive=len(new_rows),
-                        n_depart=len(deps), wave_bucket=wave_bucket,
-                        r_bucket=int(self._problem.R)) as sp:
+        with span(tel, "resolve_wave", n_arrive=len(new_rows),
+                  n_depart=len(deps), wave_bucket=wave_bucket,
+                  r_bucket=int(self._problem.R)) as sp:
             res = solvers.resolve_wave(
                 self._problem, st, new_rows, key=self._split_key(),
                 spec=self.spec, **self._resolve_kw(kw))
-            if self.telemetry is not None:
+            if tel is not None:
                 # _result already materialized res.X/breakdown on host, so
-                # the span closes on completed device work without an
-                # extra sync point
+                # this reads no device value
                 sp.attrs["objective"] = float(res.objective)
         # phase 4: admission, per arrival in priority order
         if new_rows and self._admission_active:
-            refused = self._wave_refusals(res, arr, new_rows,
-                                          prev_power, prev_viol)
+            with span(tel, "wave.admission"):
+                refused = self._wave_refusals(res, arr, new_rows,
+                                              prev_power, prev_viol)
             if refused:
                 (self._vsrs, self._sids, self._prio, self._batch_cache,
                  self._problem, self._X, self._state, self._result,
@@ -1249,12 +1260,14 @@ class OnlineEmbedder:
             if self.monitor is not None:
                 self.monitor.unstrand(sid, self._now)
         if self._defrag_due():
-            res = self._full_solve("wave", incumbent=res)
-        else:
+            with span(tel, "wave.full_solve"):
+                res = self._repack(incumbent=res)
+        with span(tel, "wave.commit"):
             self._commit(res, "wave")
         wr.result = res
         if deps:
-            self._drain_queue()
+            with span(tel, "wave.drain"):
+                self._drain_queue()
         return self._wave_deferred(wr, deferred)
 
     def _wave_deferred(self, wr: WaveResult,

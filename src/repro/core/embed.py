@@ -37,14 +37,16 @@ def _spec(method: str = "cfn-milp", effort: str = "standard",
 
 def _embed(topo: CFNTopology, vsrs: VSRBatch, spec,
            key: Optional[jax.Array] = None,
-           problem: Optional[PlacementProblem] = None) -> solvers.SolveResult:
+           problem: Optional[PlacementProblem] = None,
+           telemetry=None) -> solvers.SolveResult:
     """Spec-driven embedding dispatch -- the single batch-path consumer.
 
     ``spec.masks(problem)`` is built ONCE here and threaded into whichever
     solver ``spec.method`` selects; solvers without native masking (the
     fixed-layer baselines) are forced onto the mask by
     ``solvers.repair_to_eligible`` afterwards, so every method returns an
-    eligible placement.
+    eligible placement.  ``telemetry`` (optional) reaches the portfolio's
+    phase spans.
     """
     problem = build_problem(topo, vsrs) if problem is None else problem
     key = jax.random.PRNGKey(0) if key is None else key
@@ -72,7 +74,7 @@ def _embed(topo: CFNTopology, vsrs: VSRBatch, spec,
         res = solvers.relax(problem, key, eligible=eligible)
     elif m == "cfn-milp":
         res = solvers.solve_portfolio(problem, topo, spec, key,
-                                      eligible=eligible)
+                                      eligible=eligible, telemetry=telemetry)
     else:
         raise ValueError(f"unknown method {m!r}; choose from {METHODS}")
     if eligible is not None:

@@ -68,6 +68,7 @@ import numpy as np
 from . import dynamic, power, solvers
 from . import vsr as vsr_mod
 from .topology import CFNTopology
+from ..telemetry import span
 
 __all__ = ["Region", "RegionPartition", "ServicePlan", "FederatedBreakdown",
            "FederatedResult", "FederatedSession", "federated_breakdown",
@@ -697,11 +698,11 @@ def _traced(name: str, ledger: bool = False):
         @functools.wraps(fn)
         def wrapper(self, *args, **kwargs):
             tel = self.telemetry
-            if tel is None or self._flat is not None:
+            if self._flat is not None:
                 return fn(self, *args, **kwargs)
-            with tel.span(name):
+            with span(tel, name):
                 out = fn(self, *args, **kwargs)
-            if ledger:
+            if ledger and tel is not None:
                 self._record_fleet_energy(name)
             return out
         return wrapper

@@ -48,6 +48,7 @@ from .power import (PENALTY, PlacementAux, PlacementProblem, PlacementState,
                     _delta_objective, _move_core)
 from .topology import CFNTopology
 from ..kernels import placement_power as kpp
+from ..telemetry import span
 
 
 @dataclass
@@ -751,7 +752,8 @@ def resolve_incremental(problem: PlacementProblem,
                         pad_positions_to: Optional[int] = None,
                         pad_changed_to: Optional[int] = None,
                         spec=None,
-                        record_conv: bool = False) -> SolveResult:
+                        record_conv: bool = False,
+                        telemetry=None) -> SolveResult:
     """Warm-start re-solve after service churn: surviving services stay at
     their previous nodes, only the VMs of ``changed_rows`` (new arrivals /
     rows the caller distrusts) are actively re-placed.
@@ -783,6 +785,10 @@ def resolve_incremental(problem: PlacementProblem,
     should pass ``prev_X=None``: the previous placement is only read when
     ``state`` is absent, and materializing ``np.asarray(state.X)`` just to
     fill the argument is a dead device->host transfer per churn event.
+
+    ``telemetry`` (a ``repro.telemetry.Telemetry``, optional) spans the
+    phases: ``resolve.prep`` / ``targeted`` / ``anneal`` / ``select`` /
+    ``polish`` / ``result``.
     """
     pick = lambda v, sv, d: (v if v is not None
                              else (sv if sv is not None else d))
@@ -794,110 +800,124 @@ def resolve_incremental(problem: PlacementProblem,
     anneal_t1 = pick(anneal_t1, getattr(spec, "anneal_t1", None), 0.05)
     polish_sweeps = pick(polish_sweeps,
                          getattr(spec, "polish_sweeps", None), 2)
-    if eligible is None and spec is not None:
-        eligible = spec.masks(problem)
     key = jax.random.PRNGKey(0) if key is None else key
-    aux = build_aux(problem)
-    if state is None:
-        if prev_X is None:
-            raise ValueError("resolve_incremental needs prev_X or state")
-        state = init_state(problem, jnp.asarray(prev_X, jnp.int32))
-    # else: the caller-carried state (power.warm_state) is trusted as-is --
-    # that's the O(V*(N+P)) event path; candidates are re-scored exactly
-    # below, so carried float32 load drift cannot corrupt the result
-    changed_rows = [] if changed_rows is None else list(changed_rows)
-    free = np.asarray(aux.free_pos)
-    if free.shape[0] == 0:  # everything pinned: nothing to re-place
-        return _result(problem, state.X, "incremental")
-    el_np, cnt_np, cand_np = _eligible_np(eligible)
-    el_j = None if el_np is None else jnp.asarray(el_np)
-    if el_np is not None:
-        # the warm incumbent may predate the mask (a substrate fault can
-        # arrive after placement): project it first, so a mask-violating
-        # placement can never win the exact-objective argmin below
-        X0, moved = _project_eligible(problem, state.X, el_np)
-        if moved:
-            state = init_state(problem, apply_pins(problem, X0))
-    cands = [state.X]
-    pos_changed = free[np.isin(free[:, 0], changed_rows)]
-    # wave axis bucketing: pad the changed-position list so the targeted
-    # sweep (and the Metropolis target set below -- duplicate targets are a
-    # harmless proposal bias) compiles once per wave-shape bucket
-    pos_changed = _pad_positions(pos_changed, pad_changed_to)
+    tel = telemetry
+    with span(tel, "resolve.prep"):
+        if eligible is None and spec is not None:
+            eligible = spec.masks(problem)
+        aux = build_aux(problem)
+        if state is None:
+            if prev_X is None:
+                raise ValueError("resolve_incremental needs prev_X or state")
+            state = init_state(problem, jnp.asarray(prev_X, jnp.int32))
+        # else: the caller-carried state (power.warm_state) is trusted
+        # as-is -- that's the O(V*(N+P)) event path; candidates are
+        # re-scored exactly below, so carried float32 load drift cannot
+        # corrupt the result
+        changed_rows = [] if changed_rows is None else list(changed_rows)
+        free = np.asarray(aux.free_pos)
+        if free.shape[0] == 0:  # everything pinned: nothing to re-place
+            return _result(problem, state.X, "incremental")
+        el_np, cnt_np, cand_np = _eligible_np(eligible)
+        el_j = None if el_np is None else jnp.asarray(el_np)
+        if el_np is not None:
+            # the warm incumbent may predate the mask (a substrate fault
+            # can arrive after placement): project it first, so a
+            # mask-violating placement can never win the exact-objective
+            # argmin below
+            X0, moved = _project_eligible(problem, state.X, el_np)
+            if moved:
+                state = init_state(problem, apply_pins(problem, X0))
+        cands = [state.X]
+        pos_changed = free[np.isin(free[:, 0], changed_rows)]
+        # wave axis bucketing: pad the changed-position list so the
+        # targeted sweep (and the Metropolis target set below -- duplicate
+        # targets are a harmless proposal bias) compiles once per
+        # wave-shape bucket
+        pos_changed = _pad_positions(pos_changed, pad_changed_to)
 
     # phase 1: greedy placement of the changed VMs
     if pos_changed.shape[0]:
-        pc = jnp.asarray(pos_changed)
-        for _ in range(max(1, sweeps)):
-            state, _ = _sweep(problem, aux, state, pc, el_j)
-        cands.append(state.X)
+        with span(tel, "resolve.targeted"):
+            pc = jnp.asarray(pos_changed)
+            for _ in range(max(1, sweeps)):
+                state, _ = _sweep(problem, aux, state, pc, el_j)
+            cands.append(state.X)
 
     # phase 2: short Metropolis refinement
-    conv: Optional[Dict[str, np.ndarray]] = None
+    hist = None
     if anneal_steps > 0 and anneal_chains > 0:
-        P, V = problem.P, problem.V
-        target = pos_changed if pos_changed.shape[0] else free
-        flat = jnp.asarray((target[:, 0] * V + target[:, 1])
-                           .astype(np.int32))
-        kf, kp, ka, kx = jax.random.split(key, 4)
-        fi = jax.random.randint(kf, (anneal_steps, anneal_chains), 0,
-                                flat.shape[0])
-        j_prop = flat[fi]
-        if el_np is None:
-            p_prop = jax.random.randint(kp, (anneal_steps, anneal_chains),
-                                        0, P, jnp.int32)
-        else:
-            # destinations sampled from each proposal row's eligible set
-            # (fold_in: unmasked randint stream stays byte-identical)
-            rows = j_prop // V
-            u_dst = jax.random.uniform(jax.random.fold_in(kp, 1),
-                                       (anneal_steps, anneal_chains))
-            p_prop = _sample_eligible(u_dst, rows, jnp.asarray(cnt_np),
-                                      jnp.asarray(cand_np))
-        u_prop = jax.random.uniform(ka, (anneal_steps, anneal_chains))
-        temps = anneal_t0 * (anneal_t1 / anneal_t0) ** (
-            jnp.arange(anneal_steps) / max(1, anneal_steps - 1))
-        Xc = jnp.broadcast_to(state.X, (anneal_chains,) + state.X.shape)
-        if el_np is None:
-            rand = jax.random.randint(kx, Xc.shape, 0, P, jnp.int32)
-        else:
-            # restarted chains must also start on eligible nodes
-            u_r = jax.random.uniform(jax.random.fold_in(kx, 1), Xc.shape)
-            rand = _sample_eligible(
-                u_r, jnp.arange(problem.R)[None, :, None],
-                jnp.asarray(cnt_np), jnp.asarray(cand_np))
-        # chain 0 stays warm; the rest restart at the target positions only
-        tgt_mask = np.zeros((problem.R, V), dtype=bool)
-        tgt_mask[target[:, 0], target[:, 1]] = True
-        keep = ((jnp.arange(anneal_chains) == 0)[:, None, None]
-                | ~jnp.asarray(tgt_mask)[None])
-        Xc = jnp.where(keep, Xc, rand)
-        bX, _, hist = _anneal_scan_delta(problem, aux, Xc, j_prop, p_prop,
-                                         u_prop, temps)
-        cands.append(bX)
-        if record_conv:
-            # fixed length anneal_steps (static per effort bucket): the
-            # telemetry plane's quality-vs-steps trace for this solve
-            conv = {"best_obj": np.asarray(hist[0]),
-                    "accept_rate": np.asarray(hist[1])}
+        with span(tel, "resolve.anneal"):
+            P, V = problem.P, problem.V
+            target = pos_changed if pos_changed.shape[0] else free
+            flat = jnp.asarray((target[:, 0] * V + target[:, 1])
+                               .astype(np.int32))
+            kf, kp, ka, kx = jax.random.split(key, 4)
+            fi = jax.random.randint(kf, (anneal_steps, anneal_chains), 0,
+                                    flat.shape[0])
+            j_prop = flat[fi]
+            if el_np is None:
+                p_prop = jax.random.randint(
+                    kp, (anneal_steps, anneal_chains), 0, P, jnp.int32)
+            else:
+                # destinations sampled from each proposal row's eligible
+                # set (fold_in: unmasked randint stream stays
+                # byte-identical)
+                rows = j_prop // V
+                u_dst = jax.random.uniform(jax.random.fold_in(kp, 1),
+                                           (anneal_steps, anneal_chains))
+                p_prop = _sample_eligible(u_dst, rows, jnp.asarray(cnt_np),
+                                          jnp.asarray(cand_np))
+            u_prop = jax.random.uniform(ka, (anneal_steps, anneal_chains))
+            temps = anneal_t0 * (anneal_t1 / anneal_t0) ** (
+                jnp.arange(anneal_steps) / max(1, anneal_steps - 1))
+            Xc = jnp.broadcast_to(state.X,
+                                  (anneal_chains,) + state.X.shape)
+            if el_np is None:
+                rand = jax.random.randint(kx, Xc.shape, 0, P, jnp.int32)
+            else:
+                # restarted chains must also start on eligible nodes
+                u_r = jax.random.uniform(jax.random.fold_in(kx, 1),
+                                         Xc.shape)
+                rand = _sample_eligible(
+                    u_r, jnp.arange(problem.R)[None, :, None],
+                    jnp.asarray(cnt_np), jnp.asarray(cand_np))
+            # chain 0 stays warm; the rest restart at the target positions
+            tgt_mask = np.zeros((problem.R, V), dtype=bool)
+            tgt_mask[target[:, 0], target[:, 1]] = True
+            keep = ((jnp.arange(anneal_chains) == 0)[:, None, None]
+                    | ~jnp.asarray(tgt_mask)[None])
+            Xc = jnp.where(keep, Xc, rand)
+            bX, _, hist = _anneal_scan_delta(problem, aux, Xc, j_prop,
+                                             p_prop, u_prop, temps)
+            cands.append(bX)
 
     # pick the exact-objective best (one batched call), then polish
-    objs = [float(o) for o in
-            objective_batch(problem, jnp.stack(cands))]
-    k = int(np.argmin(objs))
-    best_obj, best_X = objs[k], cands[k]
-    history: List[float] = objs + [best_obj]
+    with span(tel, "resolve.select"):
+        objs = [float(o) for o in
+                objective_batch(problem, jnp.stack(cands))]
+        k = int(np.argmin(objs))
+        best_obj, best_X = objs[k], cands[k]
+        history: List[float] = objs + [best_obj]
     if polish_sweeps > 0:
-        state = init_state(problem, best_X)
-        pa = jnp.asarray(_pad_positions(free, pad_positions_to))
-        for _ in range(polish_sweeps):
-            state, _ = _sweep(problem, aux, state, pa, el_j)
-        obj = float(objective(problem, state.X))
-        if obj < best_obj:
-            best_obj, best_X = obj, state.X
-        history.append(best_obj)
-    res = _result(problem, best_X, "incremental", history)
-    res.conv = conv
+        with span(tel, "resolve.polish"):
+            state = init_state(problem, best_X)
+            pa = jnp.asarray(_pad_positions(free, pad_positions_to))
+            for _ in range(polish_sweeps):
+                state, _ = _sweep(problem, aux, state, pa, el_j)
+            obj = float(objective(problem, state.X))
+            if obj < best_obj:
+                best_obj, best_X = obj, state.X
+            history.append(best_obj)
+    with span(tel, "resolve.result"):
+        res = _result(problem, best_X, "incremental", history)
+        if record_conv and hist is not None:
+            # fixed length anneal_steps (static per effort bucket): the
+            # telemetry plane's quality-vs-steps trace for this solve, read
+            # after the objectives, so the anneal has finished and the
+            # read adds no wait
+            res.conv = {"best_obj": np.asarray(hist[0]),
+                        "accept_rate": np.asarray(hist[1])}
     return res
 
 
@@ -947,7 +967,8 @@ def resolve_wave(problem: PlacementProblem,
 
 def solve_portfolio(problem: PlacementProblem, topo: CFNTopology,
                     spec=None, key: Optional[jax.Array] = None,
-                    eligible: Optional[np.ndarray] = None) -> SolveResult:
+                    eligible: Optional[np.ndarray] = None,
+                    telemetry=None) -> SolveResult:
     """Best-of portfolio driven by a ``repro.api.PlacementSpec``: effort
     tier, anneal backend, and constraint masks all come from the spec
     (``eligible`` overrides ``spec.masks(problem)`` when given explicitly),
@@ -956,6 +977,10 @@ def solve_portfolio(problem: PlacementProblem, topo: CFNTopology,
 
     On instances small enough for `exhaustive` the unconstrained portfolio
     is provably optimal; tests pin it to the exhaustive optimum.
+
+    ``telemetry`` (optional) spans ``portfolio.warm_starts`` (both
+    ``coordinate`` candidates and ``fixed_layer``) and
+    ``portfolio.anneal``.
     """
     key = jax.random.PRNGKey(0) if key is None else key
     effort = getattr(spec, "effort", "standard")
@@ -966,16 +991,18 @@ def solve_portfolio(problem: PlacementProblem, topo: CFNTopology,
     candidates: List[SolveResult] = []
     # warm starts: CDC-everything and IoT-first-fit (the masked coordinate
     # sweeps project both onto the eligible set in their first pass)
-    base_cdc = np.full((problem.R, problem.V), cdc, dtype=np.int32)
-    candidates.append(coordinate(problem, base_cdc, eligible=eligible))
-    iot_ff = fixed_layer(problem, topo, "iot")
-    candidates.append(coordinate(problem, iot_ff.X, eligible=eligible))
+    with span(telemetry, "portfolio.warm_starts"):
+        base_cdc = np.full((problem.R, problem.V), cdc, dtype=np.int32)
+        candidates.append(coordinate(problem, base_cdc, eligible=eligible))
+        iot_ff = fixed_layer(problem, topo, "iot")
+        candidates.append(coordinate(problem, iot_ff.X, eligible=eligible))
     if effort in ("standard", "high"):
         k1, k2 = jax.random.split(key)
         warm = min(candidates, key=lambda r: r.objective).X
         n_steps = 4000 if effort == "standard" else 12000
-        candidates.append(anneal(problem, k1, warm, n_steps=n_steps,
-                                 backend=backend, eligible=eligible))
+        with span(telemetry, "portfolio.anneal"):
+            candidates.append(anneal(problem, k1, warm, n_steps=n_steps,
+                                     backend=backend, eligible=eligible))
         if effort == "high":
             candidates.append(genetic(problem, k2, warm, eligible=eligible))
     best = min(candidates, key=lambda r: r.objective)

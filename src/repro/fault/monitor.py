@@ -19,8 +19,9 @@ from __future__ import annotations
 
 import statistics
 import time
+from collections import deque
 from dataclasses import dataclass, field
-from typing import Callable, Dict, List, Optional, Tuple
+from typing import Callable, Deque, Dict, List, Optional, Tuple
 
 
 @dataclass
@@ -107,12 +108,16 @@ class PlacementMonitor:
     """
 
     counters: Dict[str, int] = field(default_factory=dict)
-    events: List[Tuple[str, Optional[str]]] = field(default_factory=list)
+    events: Deque[Tuple[str, Optional[str]]] = field(default_factory=deque)
     max_events: int = 256
     stranded_service_s: float = 0.0
     stranded_since: Dict[int, float] = field(default_factory=dict)
     telemetry: Optional[object] = None
     telemetry_prefix: str = "monitor"
+
+    def __post_init__(self) -> None:
+        # the ring: the newest ``max_events`` pairs, oldest first
+        self.events = deque(self.events, maxlen=self.max_events)
 
     def attach_telemetry(self, telemetry, prefix: str = "monitor") -> None:
         """Mirror this monitor's counters/events into a ``Telemetry``
@@ -124,8 +129,6 @@ class PlacementMonitor:
               n: int = 1) -> None:
         self.counters[kind] = self.counters.get(kind, 0) + n
         self.events.append((kind, detail))
-        if len(self.events) > self.max_events:
-            del self.events[:len(self.events) - self.max_events]
         tel = self.telemetry
         if tel is not None:
             tel.inc(f"{self.telemetry_prefix}.{kind}", n)
@@ -211,8 +214,6 @@ class PlacementMonitor:
                     and other.telemetry is not self.telemetry):
                 self.telemetry.inc(f"{self.telemetry_prefix}.{kind}", n)
         self.events.extend(other.events)
-        if len(self.events) > self.max_events:
-            del self.events[:len(self.events) - self.max_events]
         self.stranded_service_s += other.stranded_service_s
         for sid, t0 in other.stranded_since.items():
             self.stranded_since[sid] = min(

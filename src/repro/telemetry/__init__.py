@@ -3,8 +3,10 @@
 A low-overhead, host-side telemetry subsystem for the serving stack:
 
 * ``Telemetry`` -- the registry: counters, gauges, log2-bucketed
-  histograms, a span/trace API (``with tel.span("resolve_wave", ...)``)
-  and a JSON-lines event sink;
+  histograms, a span/trace API (``with span(tel, "resolve_wave", ...)``,
+  a no-op when ``tel`` is None; each span is also a profiler annotation
+  ``repro.<name>`` on the device trace's clock) and a JSON-lines event
+  sink;
 * ``EnergyLedger`` (``tel.ledger``) -- per-commit watts decomposed into
   Eq.(1) networking vs Eq.(2) processing, per tier / tenant / region,
   integrated to joules over a replay horizon;
@@ -23,12 +25,12 @@ every instrumented path a strict no-op -- bit-identical placements,
 zero extra compiles).  See docs/OBSERVABILITY.md.
 """
 from .ledger import EnergyLedger, tiers_of
-from .registry import Histogram, Span, Telemetry
+from .registry import Histogram, Span, Telemetry, span
 from .report import (EVENT_SCHEMA, load_events, render, summarize_events,
                      validate_events)
 
 __all__ = [
-    "Telemetry", "Span", "Histogram", "EnergyLedger", "tiers_of",
+    "Telemetry", "Span", "span", "Histogram", "EnergyLedger", "tiers_of",
     "EVENT_SCHEMA", "load_events", "validate_events", "summarize_events",
     "render",
 ]

@@ -1,16 +1,17 @@
 """The telemetry registry: counters, gauges, log-bucketed histograms, and
 a span/trace API emitting JSON-lines events.
 
-Everything here is HOST-side and synchronous-by-construction: nothing in
-this module is reachable from a jitted body (tracelint CFN101 has no jit
-roots to anchor on), nothing allocates device arrays, and nothing forces
-a device sync unless a span explicitly asks for one via ``sync=`` /
-``Span.sync(...)`` -- the one sanctioned ``jax.block_until_ready`` call,
-taken at the span BOUNDARY so the measured duration covers the device
-work without planting a sync inside traced code.
+Everything here is HOST-side: nothing in this module is reachable from a
+jitted body (tracelint CFN101 has no jit roots to anchor on), nothing
+allocates device arrays, and nothing blocks on the device or reads a
+device value.  Each span is also a ``jax.profiler.TraceAnnotation`` named
+``repro.<name>``, so under an active profiler it lands on the device
+trace's clock: the trace charges device work to a span without a block,
+and a block would change the asynchronous pipeline being measured.
 
 Overhead discipline: the registry is designed so the *disabled* path is a
-no-op (callers guard on ``telemetry is None``) and the *enabled* path is
+no-op (``span(None, ...)`` is a shared no-op context; other callers guard
+on ``telemetry is None``) and the *enabled* path is
 a few dict operations plus one buffered file write per event -- the
 ``telemetry_overhead`` benchmark (BENCH_obs.json) holds it under 2% on
 the city_p468 churn-wave workload.
@@ -23,7 +24,11 @@ from __future__ import annotations
 import json
 import math
 import time
-from typing import Any, Dict, IO, List, Optional
+from collections import deque
+from contextlib import nullcontext
+from typing import Any, Deque, Dict, IO, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 from .ledger import EnergyLedger
 
@@ -80,26 +85,21 @@ class Histogram:
 class Span:
     """One timed section.  Context manager; exception-safe (the event is
     emitted with ``ok=False`` and the error type, and the exception
-    propagates).  ``sync(value)`` registers a jax value (array / pytree)
-    to ``block_until_ready`` at exit, so device work launched inside the
-    span is charged to it."""
+    propagates).  It is timed on two clocks: ``time.perf_counter`` for the
+    histogram and the JSONL event, and a ``jax.profiler.TraceAnnotation``
+    named ``repro.<name>`` (the name only; the attrs stay on the event)
+    for the profiler's trace."""
 
-    __slots__ = ("tel", "name", "attrs", "id", "parent", "t0", "_sync")
+    __slots__ = ("tel", "name", "attrs", "id", "parent", "t0", "_ann")
 
-    def __init__(self, tel: "Telemetry", name: str,
-                 sync: Any = None, **attrs: Any) -> None:
+    def __init__(self, tel: "Telemetry", name: str, **attrs: Any) -> None:
         self.tel = tel
         self.name = name
         self.attrs = attrs
-        self._sync = sync
         self.id = -1
         self.parent: Optional[int] = None
         self.t0 = 0.0
-
-    def sync(self, value: Any) -> Any:
-        """Block on ``value`` at span exit (returns it for chaining)."""
-        self._sync = value
-        return value
+        self._ann = TraceAnnotation(f"repro.{name}")
 
     def __enter__(self) -> "Span":
         tel = self.tel
@@ -107,14 +107,13 @@ class Span:
         tel._next_id += 1
         self.parent = tel._span_stack[-1] if tel._span_stack else None
         tel._span_stack.append(self.id)
+        self._ann.__enter__()
         self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
-        if self._sync is not None:
-            import jax
-            jax.block_until_ready(self._sync)
         dur_ms = (time.perf_counter() - self.t0) * 1e3
+        self._ann.__exit__(exc_type, exc, tb)
         tel = self.tel
         if tel._span_stack and tel._span_stack[-1] == self.id:
             tel._span_stack.pop()
@@ -127,6 +126,16 @@ class Span:
         return False                                  # never swallow
 
 
+_NO_SPAN = nullcontext()
+
+
+def span(tel: Optional["Telemetry"], name: str, **attrs: Any):
+    """``tel.span(name, **attrs)``, or a shared no-op context when ``tel``
+    is None: the one helper every instrumented site goes through, so the
+    disabled path costs an ``is None`` check and allocates nothing."""
+    return _NO_SPAN if tel is None else Span(tel, name, **attrs)
+
+
 class Telemetry:
     """The registry.  One per serving process (or per experiment arm).
 
@@ -136,7 +145,8 @@ class Telemetry:
         When set, every event is appended to this file as one JSON line
         (opened lazily on the first event, closed by ``close()``).
     max_events:
-        In-memory event ring bound (the file, when set, gets everything).
+        In-memory event ring bound (the file, when set, gets everything):
+        ``events`` keeps the newest ``max_events``, oldest first.
     convergence:
         Record solver convergence traces (``SolveResult.conv``) on
         commits.  The traces are fixed-length per effort bucket -- the
@@ -156,8 +166,8 @@ class Telemetry:
         self.counters: Dict[str, float] = {}
         self.gauges: Dict[str, float] = {}
         self.hists: Dict[str, Histogram] = {}
-        self.events: List[dict] = []
         self.max_events = int(max_events)
+        self.events: Deque[dict] = deque(maxlen=self.max_events)
         self.convergence = bool(convergence)
         self.attribution_every = attribution_every
         self.jsonl_path = jsonl_path
@@ -190,8 +200,6 @@ class Telemetry:
         ev = {"type": type_, "ts": time.time()}
         ev.update(fields)
         self.events.append(ev)
-        if len(self.events) > self.max_events:
-            del self.events[:len(self.events) - self.max_events]
         if self.jsonl_path is not None:
             if self._fh is None:
                 self._fh = open(self.jsonl_path, "a")
@@ -201,8 +209,8 @@ class Telemetry:
             self._fh.write(json.dumps(ev) + "\n")
         return ev
 
-    def span(self, name: str, sync: Any = None, **attrs: Any) -> Span:
-        return Span(self, name, sync=sync, **attrs)
+    def span(self, name: str, **attrs: Any) -> Span:
+        return Span(self, name, **attrs)
 
     # -- compile attribution (count_traces hook) ---------------------------
     def attach_traces(self) -> None:

@@ -4,15 +4,19 @@ The contract under test: telemetry OBSERVES the serving stack, it never
 perturbs it.  Disabled (telemetry=None) the engine must be bit-identical
 to a never-instrumented one -- same placements, same power, same
 admission decisions, zero extra compiles -- and enabled it must record
-faithfully: span nesting and exception safety, histogram bucket edges,
+faithfully: span nesting and exception safety (on the host clock and on
+the profiler's trace), the wave's phase spans, bounded event rings,
+histogram bucket edges,
 JSONL round-trips through the report pipeline, the energy ledger summing
 exactly to the per-tenant/per-region attribution, the monitor mirror
 staying in lockstep with the standalone counters, and the compile
 attribution agreeing with ``solvers.TRACE_COUNTS``.  The package itself
 must lint clean under tracelint with an empty baseline.
 """
+import glob
 import json
 import math
+from collections import deque
 from pathlib import Path
 
 import numpy as np
@@ -22,7 +26,7 @@ from repro.api import CFNSession, FederatedSession, PlacementSpec
 from repro.core import dynamic, federation, power, solvers, topology, vsr
 from repro.fault.monitor import PlacementMonitor
 from repro.kernels import ref as kref
-from repro.telemetry import (EnergyLedger, Telemetry, load_events,
+from repro.telemetry import (EnergyLedger, Telemetry, load_events, span,
                              summarize_events, tiers_of, validate_events)
 from repro.telemetry.registry import _bucket_edge
 
@@ -63,11 +67,14 @@ def _churn(sess):
 def test_disabled_telemetry_is_identical(topo, tmp_path):
     """telemetry=None vs a live Telemetry: same placements (bitwise),
     same f64-oracle power, same admission outcomes, and the instrumented
-    run adds ZERO fresh solver compiles beyond the baseline run's."""
-    plain = _churn(CFNSession(topo, _spec(), telemetry=None))
+    run adds ZERO fresh solver compiles beyond the baseline run's.  The
+    churn's wave is the sixth event since the last full solve, so it runs
+    the spec's full portfolio solve (with its phase spans) too."""
+    spec = PlacementSpec(method="cfn-milp", effort="quick", defrag_every=6)
+    plain = _churn(CFNSession(topo, spec, telemetry=None))
     before = dict(solvers.TRACE_COUNTS)
     tel = Telemetry(jsonl_path=str(tmp_path / "run.jsonl"))
-    instr = _churn(CFNSession(topo, _spec(), telemetry=tel))
+    instr = _churn(CFNSession(topo, spec, telemetry=tel))
     fresh = {k: solvers.TRACE_COUNTS.get(k, 0) - before.get(k, 0)
              for k in solvers.TRACE_COUNTS
              if solvers.TRACE_COUNTS.get(k, 0) != before.get(k, 0)}
@@ -75,6 +82,12 @@ def test_disabled_telemetry_is_identical(topo, tmp_path):
         f"instrumented replay of an identical scenario retraced: {fresh}"
 
     assert plain.sids == instr.sids          # same admissions, same order
+    assert [st.method for st in plain.stats] == \
+        [st.method for st in instr.stats]
+    assert instr.stats[-1].event == "wave"
+    assert instr.stats[-1].method != "wave"  # the wave's full solve won
+    names = {e["name"] for e in tel.events if e["type"] == "span"}
+    assert {"wave.full_solve", "portfolio.warm_starts"} <= names
     Xp, Xi = np.asarray(plain.X), np.asarray(instr.X)
     assert np.array_equal(Xp, Xi)
     assert plain.power_w() == instr.power_w()
@@ -122,13 +135,165 @@ def test_span_exception_safe():
     assert tel.hists["span.boom.ms"].count == 1   # duration still recorded
 
 
-def test_span_sync_blocks_on_value():
-    jax = pytest.importorskip("jax")
+def test_span_helper_disabled_is_shared_noop():
+    """``span(None, ...)`` is one shared no-op context (nothing allocated,
+    nothing recorded); ``span(tel, ...)`` is a live ``Span``."""
+    a, b = span(None, "x"), span(None, "y", r_bucket=4)
+    assert a is b
+    with a as sp:
+        assert sp is None
     tel = Telemetry()
-    with tel.span("device") as sp:
-        out = sp.sync(jax.numpy.arange(8) * 2)
-    assert int(out[-1]) == 14
-    assert tel.hists["span.device.ms"].count == 1
+    with span(tel, "x", r_bucket=4) as sp:
+        assert sp.attrs == {"r_bucket": 4}
+    assert tel.counters["span.x"] == 1
+
+
+def test_event_rings_keep_bound_and_order():
+    """``Telemetry.events`` and ``PlacementMonitor.events`` are bounded
+    rings: the newest ``max_events``, oldest first, also across a merge."""
+    tel = Telemetry(max_events=3)
+    for i in range(7):
+        tel.emit("event", kind="k", n=i)
+    assert isinstance(tel.events, deque)
+    assert [e["n"] for e in tel.events] == [4, 5, 6]
+    mon = PlacementMonitor(max_events=3)
+    for i in range(5):
+        mon.count("k", detail=str(i))
+    assert list(mon.events) == [("k", "2"), ("k", "3"), ("k", "4")]
+    other = PlacementMonitor()
+    other.count("j", detail="a")
+    other.count("j", detail="b")
+    mon.merge(other)
+    assert list(mon.events) == [("k", "4"), ("j", "a"), ("j", "b")]
+    assert mon.events.maxlen == 3
+    mon.reset()
+    assert not mon.events and mon.events.maxlen == 3
+
+
+# ---------------------------------------------------------------------------
+# spans on the profiler's clock
+# ---------------------------------------------------------------------------
+
+RESOLVE_PHASES = ("prep", "targeted", "anneal", "select", "polish",
+                  "result")
+
+
+def _profiled(trace_dir, fn):
+    """Run ``fn`` under the JAX profiler and return the host plane's
+    ``repro.*`` events: {name: [(start_ns, end_ns), ...]}."""
+    import jax
+    from jax.profiler import ProfileData
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(trace_dir), profiler_options=opts)
+    try:
+        fn()
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(str(trace_dir / "plugins" / "profile" / "*"
+                          / "*.xplane.pb"))
+    spans = {}
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith("repro."):
+                    a = float(ev.start_ns)
+                    spans.setdefault(ev.name, []).append(
+                        (a, a + float(ev.duration_ns)))
+    return spans
+
+
+def _inside(spans, child, parent):
+    """Every ``child`` event lies inside some ``parent`` event."""
+    assert spans.get(child), f"no {child} in the trace"
+    for a, b in spans[child]:
+        assert any(pa <= a and b <= pb for pa, pb in spans[parent]), \
+            f"{child} at [{a}, {b}] lies in no {parent}"
+
+
+@pytest.fixture(scope="module")
+def wave_trace(topo, tmp_path_factory):
+    """One instrumented session, traced over two replace waves: the first
+    a plain wave, the second the one that makes a full solve due."""
+    tel = Telemetry(convergence=False)
+    sess = CFNSession(topo, PlacementSpec(effort="standard",
+                                          defrag_every=6), telemetry=tel)
+    sess.solve(vsr.random_vsrs(4, rng=np.random.default_rng(3), n_vms=3))
+    seeds = iter(range(20, 30))
+
+    def replace():
+        sess.apply_wave([(_svc(next(seeds)), None)], [sess.sids[0]])
+
+    replace()                                  # compiles outside the trace
+    trace_dir = tmp_path_factory.mktemp("trace")
+    waves = []
+
+    def both():
+        for _ in range(2):
+            replace()
+            waves.append(sess.stats[-1].method)
+
+    spans = _profiled(trace_dir, both)
+    return spans, waves
+
+
+def test_wave_phase_spans_nest_on_profiler_clock(wave_trace):
+    """Every span is also a ``repro.<name>`` profiler annotation:
+    ``apply_wave`` holds ``resolve_wave``, which holds the six resolve
+    phases, and the wave's own phases lie inside ``apply_wave``."""
+    spans, waves = wave_trace
+    assert len(spans["repro.apply_wave"]) == 2
+    assert len(spans["repro.resolve_wave"]) == 2
+    _inside(spans, "repro.resolve_wave", "repro.apply_wave")
+    for phase in RESOLVE_PHASES:
+        _inside(spans, f"repro.resolve.{phase}", "repro.resolve_wave")
+        assert len(spans[f"repro.resolve.{phase}"]) == 2
+    for phase in ("detach", "rebuild", "warm_state", "commit", "drain"):
+        _inside(spans, f"repro.wave.{phase}", "repro.apply_wave")
+        assert len(spans[f"repro.wave.{phase}"]) == 2
+    assert "repro.wave.admission" not in spans   # no admission budget
+    # the phases follow one another: none overlaps the next
+    order = ["repro.wave.detach", "repro.wave.rebuild",
+             "repro.wave.warm_state", "repro.resolve_wave",
+             "repro.wave.commit", "repro.wave.drain"]
+    for w in range(2):
+        ends = [spans[n][w] for n in order]
+        assert all(e0[1] <= e1[0] for e0, e1 in zip(ends, ends[1:]))
+
+
+def test_full_solve_wave_spans_portfolio_phases(wave_trace):
+    """The wave that makes a full solve due holds ``wave.full_solve``,
+    which holds the portfolio's phases, and commits after it."""
+    spans, waves = wave_trace
+    assert waves[0] == "wave" and waves[1] != "wave"
+    full, = spans["repro.wave.full_solve"]
+    second = spans["repro.apply_wave"][1]
+    assert second[0] <= full[0] and full[1] <= second[1]
+    _inside(spans, "repro.portfolio.warm_starts", "repro.wave.full_solve")
+    _inside(spans, "repro.portfolio.anneal", "repro.wave.full_solve")
+    commit = spans["repro.wave.commit"][1]
+    assert full[1] <= commit[0]
+
+
+def test_span_annotation_closes_on_exception(tmp_path):
+    """A span that raises still closes its profiler annotation: the next
+    span is its sibling on the trace, not its child."""
+    tel = Telemetry()
+
+    def work():
+        with pytest.raises(ValueError):
+            with span(tel, "boom"):
+                raise ValueError("no")
+        with span(tel, "after"):
+            pass
+
+    spans = _profiled(tmp_path, work)
+    (a0, b0), = spans["repro.boom"]
+    (a1, b1), = spans["repro.after"]
+    assert b0 <= a1
+    assert tel.hists["span.boom.ms"].count == 1
 
 
 # ---------------------------------------------------------------------------
