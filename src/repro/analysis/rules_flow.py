@@ -171,6 +171,11 @@ CACHE_CAPS: Dict[str, int] = {
     "anneal_delta": 64,
     "anneal_full": 32,
     "solve_regions": 32,
+    # one program per anneal: the re-solve's and anneal()'s per problem
+    # shape (and backend), the fused kernel's best-chain pick
+    "resolve_anneal": 64,
+    "anneal_program": 32,
+    "fused_pick": 32,
 }
 DEFAULT_CACHE_CAP = 64
 

@@ -441,12 +441,98 @@ def auto_backend(platform: str, P: int, N: int, J: int, D: int,
     return "delta"
 
 
+def _ladder(t0, decay, n_steps: int) -> jnp.ndarray:
+    """Geometric temperature ladder: ``t0`` down to ``t0 * decay`` over
+    ``n_steps`` steps.  Callers take ``decay = t1 / t0`` on the host, in
+    double precision, and the barrier hides the divisor's value from XLA,
+    which would otherwise multiply by its rounded reciprocal: so the
+    ladder rounds as ``t0 * (t1 / t0) ** (arange(n_steps) / (n_steps -
+    1))`` does op by op."""
+    last = jax.lax.optimization_barrier(jnp.float32(max(1, n_steps - 1)))
+    return t0 * decay ** (jnp.arange(n_steps) / last)
+
+
+def _finish(problem: PlacementProblem, X):
+    """The pinned placement and its breakdown: what ``_result`` computes,
+    traced inside an anneal program so its read is the program's one."""
+    X = apply_pins(problem, X)
+    return X, evaluate(problem, X)
+
+
+def _anneal_inputs(problem: PlacementProblem, aux: PlacementAux,
+                   key: jax.Array, X, t0, decay, n_steps: int,
+                   n_chains: int, cnt=None, cand=None):
+    """``anneal``'s chains and proposal stream, traced: chain 0 at the
+    warm start ``X``, the rest restarted at random (from each row's
+    eligible set when ``cnt``/``cand`` are given), pins applied, then the
+    ``_anneal_proposals`` stream with its flat VM indices gathered, and
+    the temperature ladder.  Returns ``(Xc, j_prop, p_prop, u_prop,
+    temps)``, proposals ``[n_steps, n_chains]``."""
+    R, V, P = problem.R, problem.V, problem.P
+    k_init, k_prop = jax.random.split(key)
+    X = apply_pins(problem, X)
+    if cnt is None:
+        rand = jax.random.randint(k_init, (n_chains, R, V), 0, P, jnp.int32)
+    else:
+        # restarted chains must also start on eligible nodes (fold_in:
+        # independent of the unmasked randint, which stays byte-identical)
+        u_r = jax.random.uniform(jax.random.fold_in(k_init, 1),
+                                 (n_chains, R, V))
+        rand = _sample_eligible(u_r, jnp.arange(R)[None, :, None], cnt, cand)
+    keep = (jnp.arange(n_chains) == 0)[:, None, None]
+    Xc = jax.vmap(lambda x: apply_pins(problem, x))(jnp.where(keep, X, rand))
+    fi, p_prop, u_prop = _anneal_proposals(k_prop, aux, n_steps, n_chains, P,
+                                           V=V, cnt=cnt, cand=cand)
+    return (Xc, aux.free_flat[fi], p_prop, u_prop,
+            _ladder(t0, decay, n_steps))
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("n_steps", "n_chains", "backend"))
+@count_traces("anneal_program")
+def _anneal_program(problem: PlacementProblem, aux: PlacementAux,
+                    key: jax.Array, X, t0, decay, cnt=None, cand=None, *,
+                    n_steps: int, n_chains: int, backend: str):
+    """One ``anneal`` call as one device program.
+
+    ``backend="delta"`` / ``"full"``: chains, proposals, the scan, the
+    best chain and its breakdown; returns ``(X, breakdown, sampled
+    history, (best_obj, accept_rate))``, the history sampled every
+    ``max(1, n_steps // 50)`` steps.  ``backend="fused"``: the kernel's
+    inputs ``(Xc, j_prop.T, p_prop.T, u_prop.T, temps)`` -- the kernel
+    stays its own launch (``kops.fused_anneal``) and ``_fused_pick`` takes
+    its best chain."""
+    Xc, j_prop, p_prop, u_prop, temps = _anneal_inputs(
+        problem, aux, key, X, t0, decay, n_steps, n_chains, cnt, cand)
+    if backend == "fused":
+        return Xc, j_prop.T, p_prop.T, u_prop.T, temps
+    if backend == "full":
+        bX, _, hist = _anneal_scan_full(problem, Xc, j_prop, p_prop, u_prop,
+                                        temps)
+    else:
+        bX, _, hist = _anneal_scan_delta(problem, aux, Xc, j_prop, p_prop,
+                                         u_prop, temps)
+    X, bd = _finish(problem, bX)
+    return X, bd, hist[0][:: max(1, n_steps // 50)], hist
+
+
+@jax.jit
+@count_traces("fused_pick")
+def _fused_pick(problem: PlacementProblem, bXc, stats):
+    """The fused kernel's best chain, pinned, its breakdown and the
+    kernel's own best objective for it."""
+    k = jnp.argmin(stats[:, 0])
+    X, bd = _finish(problem, bXc[k])
+    return X, bd, stats[k, 0]
+
+
 def anneal(problem: PlacementProblem, key: jax.Array, X0: np.ndarray,
            n_chains: int = 32, n_steps: int = 4000,
            t0: float = 50.0, t1: float = 0.05,
            backend: str = "auto",
            eligible: Optional[np.ndarray] = None,
-           record_conv: bool = False) -> SolveResult:
+           record_conv: bool = False,
+           telemetry=None) -> SolveResult:
     """Batched Metropolis chains on incremental (delta-evaluated) state.
 
     backend:
@@ -473,6 +559,12 @@ def anneal(problem: PlacementProblem, key: jax.Array, X0: np.ndarray,
     always COMPUTE the trace; the flag only materializes it host-side, so
     recording can never retrace (CFN108).  Not available on the fused
     Pallas backend (chain state stays in VMEM).
+
+    The chains' start, the proposal draws, the chains and the best
+    chain's breakdown run as one device program (``_anneal_program``),
+    read back once; the fused backend launches its kernel between that
+    program and ``_fused_pick``.  ``telemetry`` (optional) counts the
+    programs launched: ``solver.anneal_programs{site=portfolio}``.
     """
     R, V, P = problem.R, problem.V, problem.P
     aux = build_aux(problem)
@@ -485,51 +577,34 @@ def anneal(problem: PlacementProblem, key: jax.Array, X0: np.ndarray,
         # every VM is pinned (e.g. single-VM VSRs): nothing to anneal
         return _result(problem, jnp.asarray(X0, jnp.int32), "anneal")
     el_np, cnt_np, cand_np = _eligible_np(eligible)
-    k_init, k_prop = jax.random.split(key)
-    X = apply_pins(problem, jnp.asarray(X0, jnp.int32))
+    X = np.asarray(X0, np.int32)
     if el_np is not None:
-        Xp, _ = _project_eligible(problem, X, el_np)
-        X = apply_pins(problem, Xp)
-    Xc = jnp.broadcast_to(X, (n_chains, R, V)).copy()
-    # randomize all but chain 0 (keep one chain at the warm start)
-    if el_np is None:
-        rand = jax.random.randint(k_init, (n_chains, R, V), 0, P, jnp.int32)
-    else:
-        # restarted chains must also start on eligible nodes (fold_in:
-        # independent of the unmasked randint, which stays byte-identical)
-        u_r = jax.random.uniform(jax.random.fold_in(k_init, 1),
-                                 (n_chains, R, V))
-        rand = _sample_eligible(u_r, jnp.arange(R)[None, :, None],
-                                jnp.asarray(cnt_np), jnp.asarray(cand_np))
-    keep = (jnp.arange(n_chains) == 0)[:, None, None]
-    Xc = jax.vmap(lambda x: apply_pins(problem, x))(jnp.where(keep, Xc, rand))
-
-    temps = t0 * (t1 / t0) ** (jnp.arange(n_steps) / max(1, n_steps - 1))
-    fi, p_prop, u_prop = _anneal_proposals(k_prop, aux, n_steps, n_chains, P,
-                                           V=V, cnt=cnt_np, cand=cand_np)
-    j_prop = aux.free_flat[fi]                            # [n_steps, n_chains]
-
+        X, _ = _project_eligible(
+            problem, apply_pins(problem, jnp.asarray(X)), el_np)
+    out = _anneal_program(problem, aux, key, X, t0, t1 / t0, cnt_np,
+                          cand_np, n_steps=n_steps, n_chains=n_chains,
+                          backend=backend)
     if backend == "fused":
         from ..kernels import ops as kops
         el_j = None if el_np is None else jnp.asarray(el_np)
-        bXc, stats = kops.fused_anneal(problem, aux, Xc, j_prop.T, p_prop.T,
-                                       u_prop.T, temps, eligible=el_j)
-        k = int(jnp.argmin(stats[:, 0]))
-        return _result(problem, np.asarray(bXc[k]), "anneal(fused)",
-                       [float(stats[k, 0])])
-    if backend == "full":
-        bX, bobj, hist = _anneal_scan_full(problem, Xc, j_prop, p_prop,
-                                           u_prop, temps)
-    else:
-        bX, bobj, hist = _anneal_scan_delta(problem, aux, Xc, j_prop, p_prop,
-                                            u_prop, temps)
+        bXc, stats = kops.fused_anneal(problem, aux, *out, eligible=el_j)
+        Xb, bd, best = jax.device_get(_fused_pick(problem, bXc, stats))
+        if telemetry is not None:
+            telemetry.inc("solver.anneal_programs", 3, site="portfolio",
+                          backend=backend)
+        return SolveResult(X=Xb, breakdown=bd, method="anneal(fused)",
+                           history=[float(best)])
+    Xb, bd, hist_s, hist = out
+    Xb, bd, hist_s, hist = jax.device_get(
+        (Xb, bd, hist_s, hist if record_conv else None))
+    if telemetry is not None:
+        telemetry.inc("solver.anneal_programs", site="portfolio",
+                      backend=backend)
     tag = "anneal" if backend == "delta" else f"anneal({backend})"
-    res = _result(problem, np.asarray(bX), tag,
-                  [float(h) for h in
-                   np.asarray(hist[0][:: max(1, n_steps // 50)])])
+    res = SolveResult(X=Xb, breakdown=bd, method=tag,
+                      history=[float(h) for h in hist_s])
     if record_conv:
-        res.conv = {"best_obj": np.asarray(hist[0]),
-                    "accept_rate": np.asarray(hist[1])}
+        res.conv = {"best_obj": hist[0], "accept_rate": hist[1]}
     return res
 
 
@@ -737,6 +812,61 @@ def _pad_positions(pos: np.ndarray, m: Optional[int]) -> np.ndarray:
         [pos, np.tile(pos[:1], (m - pos.shape[0], 1))])
 
 
+def _resolve_anneal_inputs(key: jax.Array, target, n_target, X, t0, decay,
+                           n_steps: int, n_chains: int, P: int,
+                           cnt=None, cand=None):
+    """The re-solve's Metropolis chains and proposals, traced: proposals
+    range over the first ``n_target`` rows of the ``target`` positions
+    ``[L, 2]`` (duplicates from bucket padding are a harmless bias; rows
+    past ``n_target`` repeat the first and are never drawn),
+    destinations from each row's eligible set when ``cnt``/``cand`` are
+    given; chain 0 stays at ``X``, the rest restart at the target
+    positions.  Returns ``(Xc, j_prop, p_prop, u_prop, temps)``,
+    proposals ``[n_steps, n_chains]``."""
+    R, V = X.shape
+    shape = (n_steps, n_chains)
+    flat = target[:, 0] * V + target[:, 1]
+    kf, kp, ka, kx = jax.random.split(key, 4)
+    j_prop = flat[jax.random.randint(kf, shape, 0, n_target)]
+    if cnt is None:
+        p_prop = jax.random.randint(kp, shape, 0, P, jnp.int32)
+    else:
+        # destinations sampled from each proposal row's eligible set
+        # (fold_in: the unmasked randint stream stays byte-identical)
+        u_dst = jax.random.uniform(jax.random.fold_in(kp, 1), shape)
+        p_prop = _sample_eligible(u_dst, j_prop // V, cnt, cand)
+    u_prop = jax.random.uniform(ka, shape)
+    if cnt is None:
+        rand = jax.random.randint(kx, (n_chains, R, V), 0, P, jnp.int32)
+    else:
+        # restarted chains must also start on eligible nodes
+        u_r = jax.random.uniform(jax.random.fold_in(kx, 1), (n_chains, R, V))
+        rand = _sample_eligible(u_r, jnp.arange(R)[None, :, None], cnt, cand)
+    tgt = jnp.zeros((R, V), bool).at[target[:, 0], target[:, 1]].set(True)
+    keep = (jnp.arange(n_chains) == 0)[:, None, None] | ~tgt[None]
+    Xc = jnp.where(keep, X, rand)
+    return Xc, j_prop, p_prop, u_prop, _ladder(t0, decay, n_steps)
+
+
+@functools.partial(jax.jit, static_argnames=("n_steps", "n_chains"))
+@count_traces("resolve_anneal")
+def _resolve_anneal(problem: PlacementProblem, aux: PlacementAux,
+                    key: jax.Array, target, n_target, X, t0, decay,
+                    cnt=None, cand=None, *, n_steps: int, n_chains: int):
+    """``resolve_incremental``'s phase 2 as one device program: the
+    proposals and chains of ``_resolve_anneal_inputs`` run through
+    ``_anneal_scan_delta``.  ``target`` is padded to a length fixed per
+    problem shape and ``n_target`` is traced, so every wave bucket runs
+    the one compiled program.  Returns the best chain's placement and the
+    per-step ``(best_obj, accept_rate)`` trace."""
+    Xc, j_prop, p_prop, u_prop, temps = _resolve_anneal_inputs(
+        key, target, n_target, X, t0, decay, n_steps, n_chains, problem.P,
+        cnt, cand)
+    bX, _, hist = _anneal_scan_delta(problem, aux, Xc, j_prop, p_prop,
+                                     u_prop, temps)
+    return bX, hist
+
+
 def resolve_incremental(problem: PlacementProblem,
                         prev_X: Optional[np.ndarray] = None,
                         key: Optional[jax.Array] = None,
@@ -848,48 +978,18 @@ def resolve_incremental(problem: PlacementProblem,
     hist = None
     if anneal_steps > 0 and anneal_chains > 0:
         with span(tel, "resolve.anneal"):
-            P, V = problem.P, problem.V
+            # the changed positions, else every free VM; padded past the
+            # largest wave bucket, so no wave size compiles a new program
             target = pos_changed if pos_changed.shape[0] else free
-            flat = jnp.asarray((target[:, 0] * V + target[:, 1])
-                               .astype(np.int32))
-            kf, kp, ka, kx = jax.random.split(key, 4)
-            fi = jax.random.randint(kf, (anneal_steps, anneal_chains), 0,
-                                    flat.shape[0])
-            j_prop = flat[fi]
-            if el_np is None:
-                p_prop = jax.random.randint(
-                    kp, (anneal_steps, anneal_chains), 0, P, jnp.int32)
-            else:
-                # destinations sampled from each proposal row's eligible
-                # set (fold_in: unmasked randint stream stays
-                # byte-identical)
-                rows = j_prop // V
-                u_dst = jax.random.uniform(jax.random.fold_in(kp, 1),
-                                           (anneal_steps, anneal_chains))
-                p_prop = _sample_eligible(u_dst, rows, jnp.asarray(cnt_np),
-                                          jnp.asarray(cand_np))
-            u_prop = jax.random.uniform(ka, (anneal_steps, anneal_chains))
-            temps = anneal_t0 * (anneal_t1 / anneal_t0) ** (
-                jnp.arange(anneal_steps) / max(1, anneal_steps - 1))
-            Xc = jnp.broadcast_to(state.X,
-                                  (anneal_chains,) + state.X.shape)
-            if el_np is None:
-                rand = jax.random.randint(kx, Xc.shape, 0, P, jnp.int32)
-            else:
-                # restarted chains must also start on eligible nodes
-                u_r = jax.random.uniform(jax.random.fold_in(kx, 1),
-                                         Xc.shape)
-                rand = _sample_eligible(
-                    u_r, jnp.arange(problem.R)[None, :, None],
-                    jnp.asarray(cnt_np), jnp.asarray(cand_np))
-            # chain 0 stays warm; the rest restart at the target positions
-            tgt_mask = np.zeros((problem.R, V), dtype=bool)
-            tgt_mask[target[:, 0], target[:, 1]] = True
-            keep = ((jnp.arange(anneal_chains) == 0)[:, None, None]
-                    | ~jnp.asarray(tgt_mask)[None])
-            Xc = jnp.where(keep, Xc, rand)
-            bX, _, hist = _anneal_scan_delta(problem, aux, Xc, j_prop,
-                                             p_prop, u_prop, temps)
+            pad = max(_pow2(problem.R * problem.V), target.shape[0])
+            bX, hist = _resolve_anneal(
+                problem, aux, key, _pad_positions(target, pad),
+                target.shape[0], state.X, anneal_t0, anneal_t1 / anneal_t0,
+                cnt_np, cand_np, n_steps=anneal_steps,
+                n_chains=anneal_chains)
+            if tel is not None:
+                tel.inc("solver.anneal_programs", site="resolve",
+                        backend="delta")
             cands.append(bX)
 
     # pick the exact-objective best (one batched call), then polish
@@ -1002,7 +1102,8 @@ def solve_portfolio(problem: PlacementProblem, topo: CFNTopology,
         n_steps = 4000 if effort == "standard" else 12000
         with span(telemetry, "portfolio.anneal"):
             candidates.append(anneal(problem, k1, warm, n_steps=n_steps,
-                                     backend=backend, eligible=eligible))
+                                     backend=backend, eligible=eligible,
+                                     telemetry=telemetry))
         if effort == "high":
             candidates.append(genetic(problem, k2, warm, eligible=eligible))
     best = min(candidates, key=lambda r: r.objective)

@@ -52,8 +52,11 @@ def _check(entry, measured, bound):
 
 def test_churn_wave_traces_within_static_bounds(bounds):
     """A two-bucket churn trace through ``resolve_wave``: the realized
-    ``sweep`` / ``anneal_delta`` trace counts sit inside the CFN108
-    scenario bounds for the ``resolve_incremental`` call sites."""
+    ``sweep`` trace counts sit inside the CFN108 scenario bounds for the
+    ``resolve_incremental`` call sites; the anneal program
+    (``resolve_anneal``, and ``anneal_delta`` traced inside it) compiles
+    once for both wave buckets, as its target list is padded past the
+    largest bucket.  Replaying the same waves compiles nothing."""
     topo = topology.paper_topology()
     # n_vms=5 is unique to this test: every compile below is fresh
     vs = vsr.random_vsrs(6, rng=0, n_vms=5,
@@ -79,10 +82,51 @@ def test_churn_wave_traces_within_static_bounds(bounds):
     cards = {"resolve_incremental.pad_changed_to": len(realized),
              # polish pads to one fixed all-free-VM list per problem shape
              "resolve_incremental.pad_positions_to": 1}
-    for entry in ("sweep", "anneal_delta"):
+    for entry in ("sweep", "resolve_anneal"):
         bound = bounds[entry].evaluate(sites=["resolve_incremental"],
                                        axis_cards=cards)
         _check(entry, d.get(entry, 0), bound)
+    assert d.get("resolve_anneal", 0) == 1
+    _check("anneal_delta", d.get("anneal_delta", 0),
+           bounds["anneal_delta"].evaluate(sites=["_resolve_anneal"]))
+
+    before = dict(solvers.TRACE_COUNTS)
+    for rows in waves:
+        solvers.resolve_wave(problem, state, rows, key=key, **kw)
+    assert not any(_deltas(before).values()), _deltas(before)
+
+
+def test_anneal_programs_within_static_bounds(bounds):
+    """``solvers.anneal`` on the delta and the fused backend (the kernel
+    in interpret mode here): ``anneal_program`` compiles once per backend,
+    ``fused_pick`` once, each inside its CFN108 scenario bound for the
+    ``anneal`` call site, and a second solve of the same shapes with a new
+    key compiles nothing."""
+    topo = topology.paper_topology()
+    # n_vms=7 is unique to this test: every compile below is fresh
+    vs = vsr.random_vsrs(3, rng=1, n_vms=7,
+                         source_nodes=topo.layer_indices("iot")[:2])
+    problem = power.build_problem(topo, vs)
+    X0 = solvers.fixed_layer(problem, topo, "iot").X
+    backends = ("delta", "fused")
+
+    def solve_all(key):
+        for backend in backends:
+            solvers.anneal(problem, key, X0, n_chains=4, n_steps=40,
+                           backend=backend)
+
+    before = dict(solvers.TRACE_COUNTS)
+    solve_all(jax.random.PRNGKey(0))
+    d = _deltas(before)
+    assert d.get("anneal_program", 0) == len(backends)
+    cards = {"anneal.backend": len(backends)}
+    for entry in ("anneal_program", "fused_pick"):
+        bound = bounds[entry].evaluate(sites=["anneal"], axis_cards=cards)
+        _check(entry, d.get(entry, 0), bound)
+
+    before = dict(solvers.TRACE_COUNTS)
+    solve_all(jax.random.PRNGKey(1))
+    assert not any(_deltas(before).values()), _deltas(before)
 
 
 def test_federated_solve_regions_within_static_bound(bounds):

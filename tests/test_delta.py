@@ -305,3 +305,109 @@ def test_coordinate_on_delta_engine_still_descends(topo):
     assert all(hist[i + 1] <= hist[i] + 1e-6 for i in range(len(hist) - 1))
     # the returned incumbent matches its reported objective
     assert abs(res.objective - hist[-1]) <= 1e-3 + 1e-6 * abs(hist[-1])
+
+
+def _eager_anneal_streams(prob, aux, key, X0, n_chains, n_steps, t0, t1,
+                          el=None, cnt=None, cand=None):
+    """``solvers.anneal``'s chains' start, ``_anneal_proposals`` stream
+    and temperatures as they were drawn op by op before the anneal became
+    one device program: ``(Xc, j_prop, p_prop, u_prop, temps)``."""
+    R, V, P = prob.R, prob.V, prob.P
+    k_init, k_prop = jax.random.split(key)
+    X = power.apply_pins(prob, jnp.asarray(X0, jnp.int32))
+    if el is not None:
+        Xp, _ = solvers._project_eligible(prob, X, el)
+        X = power.apply_pins(prob, Xp)
+    Xc = jnp.broadcast_to(X, (n_chains, R, V)).copy()
+    if el is None:
+        rand = jax.random.randint(k_init, (n_chains, R, V), 0, P, jnp.int32)
+    else:
+        u_r = jax.random.uniform(jax.random.fold_in(k_init, 1),
+                                 (n_chains, R, V))
+        rand = solvers._sample_eligible(u_r, jnp.arange(R)[None, :, None],
+                                        jnp.asarray(cnt), jnp.asarray(cand))
+    keep = (jnp.arange(n_chains) == 0)[:, None, None]
+    Xc = jax.vmap(lambda x: power.apply_pins(prob, x))(
+        jnp.where(keep, Xc, rand))
+    temps = t0 * (t1 / t0) ** (jnp.arange(n_steps) / max(1, n_steps - 1))
+    fi, p_prop, u_prop = solvers._anneal_proposals(
+        k_prop, aux, n_steps, n_chains, P, V=V, cnt=cnt, cand=cand)
+    return Xc, aux.free_flat[fi], p_prop, u_prop, temps
+
+
+def _eager_anneal(prob, key, X0, n_chains, n_steps, t0, t1, backend,
+                  eligible=None):
+    """``solvers.anneal`` as it ran op by op: the eager streams, the
+    backend's chains, the best chain's pick and ``_result``."""
+    aux = power.build_aux(prob)
+    el, cnt, cand = solvers._eligible_np(eligible)
+    Xc, j_prop, p_prop, u_prop, temps = _eager_anneal_streams(
+        prob, aux, key, X0, n_chains, n_steps, t0, t1, el, cnt, cand)
+    if backend == "fused":
+        bXc, stats = ops.fused_anneal(
+            prob, aux, Xc, j_prop.T, p_prop.T, u_prop.T, temps,
+            eligible=None if el is None else jnp.asarray(el))
+        k = int(jnp.argmin(stats[:, 0]))
+        return solvers._result(prob, np.asarray(bXc[k]), "anneal(fused)",
+                               [float(stats[k, 0])])
+    if backend == "full":
+        bX, _, hist = solvers._anneal_scan_full(prob, Xc, j_prop, p_prop,
+                                                u_prop, temps)
+    else:
+        bX, _, hist = solvers._anneal_scan_delta(prob, aux, Xc, j_prop,
+                                                 p_prop, u_prop, temps)
+    tag = "anneal" if backend == "delta" else f"anneal({backend})"
+    return solvers._result(prob, np.asarray(bX), tag,
+                           [float(h) for h in
+                            np.asarray(hist[0][:: max(1, n_steps // 50)])])
+
+
+@pytest.mark.parametrize("masked", [False, True])
+def test_anneal_program_streams_match_eager(topo, masked):
+    """For a fixed key, the one-program anneal draws the chains' start,
+    the proposal stream and the temperatures the eager ops drew, byte for
+    byte, with and without an eligibility mask."""
+    prob = _problem(topo, n_vsrs=5, seed=4)
+    aux = power.build_aux(prob)
+    X0 = solvers.fixed_layer(prob, topo, "iot").X
+    el = np.ones((prob.R, prob.P), bool)
+    el[:, 1::4] = False
+    el, cnt, cand = solvers._eligible_np(el if masked else None)
+    key = jax.random.PRNGKey(17)
+    S, C, t0, t1 = 90, 5, 9.0, 0.02
+    want = _eager_anneal_streams(prob, aux, key, X0, C, S, t0, t1, el, cnt,
+                                 cand)
+    X = np.asarray(X0, np.int32)
+    if masked:
+        X, _ = solvers._project_eligible(
+            prob, power.apply_pins(prob, jnp.asarray(X)), el)
+    got = jax.jit(solvers._anneal_inputs, static_argnums=(6, 7))(
+        prob, aux, key, X, t0, t1 / t0, S, C, cnt, cand)
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+@pytest.mark.parametrize("backend", ["delta", "full", "fused"])
+def test_anneal_program_matches_eager_anneal(topo, backend):
+    """For a fixed key, ``solvers.anneal`` -- one device program, the
+    fused kernel its own launch between two -- commits the placement the
+    eager ops did, bit for bit, with the same breakdown, method and
+    history; with an eligibility mask the placement and breakdown too."""
+    prob = _problem(topo, n_vsrs=5, seed=4)
+    X0 = solvers.fixed_layer(prob, topo, "iot").X
+    key = jax.random.PRNGKey(13)
+    kw = dict(n_chains=6, n_steps=150, t0=9.0, t1=0.02)
+    got = solvers.anneal(prob, key, X0, backend=backend, **kw)
+    want = _eager_anneal(prob, key, X0, backend=backend, **kw)
+    np.testing.assert_array_equal(got.X, want.X)
+    assert got.X.dtype == want.X.dtype
+    for a, b in zip(got.breakdown, want.breakdown):
+        np.testing.assert_array_equal(a, b)
+    assert (got.method, got.history) == (want.method, want.history)
+
+    el = np.ones((prob.R, prob.P), bool)
+    el[:, 1::4] = False
+    got = solvers.anneal(prob, key, X0, backend=backend, eligible=el, **kw)
+    want = _eager_anneal(prob, key, X0, backend=backend, eligible=el, **kw)
+    np.testing.assert_array_equal(got.X, want.X)
+    assert got.objective == want.objective

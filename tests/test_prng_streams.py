@@ -8,6 +8,7 @@ whether or not a mask is in play, proposal and acceptance streams are
 statistically independent, and the seeded solvers stay deterministic.
 """
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -110,3 +111,104 @@ def test_resolve_incremental_and_wave_deterministic(setup):
     w2 = solvers.resolve_wave(problem, st, [0, 1], key=key, anneal_steps=40,
                               anneal_chains=4)
     np.testing.assert_array_equal(np.asarray(w1.X), np.asarray(w2.X))
+
+
+# ---------------------------------------------------------------------------
+# the re-solve's anneal as one program: same streams as the eager ops
+# ---------------------------------------------------------------------------
+
+def _eager_resolve_anneal(problem, aux, key, target, X, t0, decay,
+                          cnt=None, cand=None, *, n_steps, n_chains):
+    """``resolve_incremental``'s phase 2 as it ran op by op before it
+    became one program, on the unpadded ``target`` list: the best chain
+    and trace ``solvers._resolve_anneal`` returns, and the streams it fed
+    the scan."""
+    target = np.asarray(target)
+    R, V, P = problem.R, problem.V, problem.P
+    S, C = n_steps, n_chains
+    flat = jnp.asarray((target[:, 0] * V + target[:, 1]).astype(np.int32))
+    kf, kp, ka, kx = jax.random.split(key, 4)
+    fi = jax.random.randint(kf, (S, C), 0, flat.shape[0])
+    j_prop = flat[fi]
+    if cnt is None:
+        p_prop = jax.random.randint(kp, (S, C), 0, P, jnp.int32)
+    else:
+        u_dst = jax.random.uniform(jax.random.fold_in(kp, 1), (S, C))
+        p_prop = solvers._sample_eligible(u_dst, j_prop // V,
+                                          jnp.asarray(cnt),
+                                          jnp.asarray(cand))
+    u_prop = jax.random.uniform(ka, (S, C))
+    temps = t0 * decay ** (jnp.arange(S) / max(1, S - 1))
+    Xc = jnp.broadcast_to(X, (C,) + X.shape)
+    if cnt is None:
+        rand = jax.random.randint(kx, Xc.shape, 0, P, jnp.int32)
+    else:
+        u_r = jax.random.uniform(jax.random.fold_in(kx, 1), Xc.shape)
+        rand = solvers._sample_eligible(u_r, jnp.arange(R)[None, :, None],
+                                        jnp.asarray(cnt), jnp.asarray(cand))
+    tgt = np.zeros((R, V), dtype=bool)
+    tgt[target[:, 0], target[:, 1]] = True
+    keep = (jnp.arange(C) == 0)[:, None, None] | ~jnp.asarray(tgt)[None]
+    streams = (jnp.where(keep, Xc, rand), j_prop, p_prop, u_prop, temps)
+    bX, _, hist = solvers._anneal_scan_delta(problem, aux, *streams)
+    return (bX, hist), streams
+
+
+@pytest.mark.parametrize("masked", [False, True])
+def test_resolve_anneal_program_matches_eager_streams(setup, masked):
+    """For a fixed key, the one-program phase 2 draws the same chain
+    starts, targets, destinations, acceptance uniforms and temperatures
+    as the eager ops did, byte for byte, and its chains end on the same
+    placement with the same acceptances.  The best-objective trace may
+    differ in float32 rounding: the program's start loads can round
+    differently in the last place."""
+    problem, aux = setup
+    X = power.init_state(problem, np.asarray(solvers.fixed_layer(
+        problem, topology.paper_topology(), "iot").X, np.int32)).X
+    _, cnt, cand = solvers._eligible_np(_mask(problem) if masked else None)
+    # a padded target list: row 1's free VMs, the first one repeated
+    free = np.asarray(aux.free_pos)
+    target = solvers._pad_positions(free[free[:, 0] == 1], 4)
+    key = jax.random.PRNGKey(21)
+    rest = (X, 5.0, 0.05 / 5.0)
+    kw = dict(n_steps=70, n_chains=4)
+    (bX_e, hist_e), streams_e = _eager_resolve_anneal(
+        problem, aux, key, target, *rest, cnt, cand, **kw)
+    # the program's target list: padded past the bucket, its length traced
+    args = (key, solvers._pad_positions(target, 16), len(target)) + rest
+    streams = jax.jit(solvers._resolve_anneal_inputs,
+                      static_argnums=(6, 7, 8))(*args, 70, 4, problem.P,
+                                                cnt, cand)
+    for got, want in zip(streams, streams_e):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    bX, hist = solvers._resolve_anneal(problem, aux, *args, cnt, cand, **kw)
+    np.testing.assert_array_equal(np.asarray(bX), np.asarray(bX_e))
+    np.testing.assert_array_equal(np.asarray(hist[1]), np.asarray(hist_e[1]))
+    np.testing.assert_allclose(np.asarray(hist[0]), np.asarray(hist_e[0]),
+                               rtol=1e-3)
+
+
+@pytest.mark.parametrize("masked", [False, True])
+def test_resolve_wave_placements_match_eager_anneal(topo, masked,
+                                                    monkeypatch):
+    """``resolve_wave`` on a fixed wave commits the same placement, bit
+    for bit, with the one-program anneal as with the eager ops, and the
+    same exact-objective history (12 services: enough that the anneal's
+    candidate depends on its streams)."""
+    problem = power.build_problem(topo, vsr.random_vsrs(
+        12, rng=0, source_nodes=topo.layer_indices("iot")[:2]))
+    X0 = np.asarray(solvers.fixed_layer(problem, topology.paper_topology(),
+                                        "iot").X, np.int32)
+    st = power.init_state(problem, X0)
+    kw = dict(key=jax.random.PRNGKey(8), anneal_steps=60, anneal_chains=4,
+              eligible=_mask(problem) if masked else None)
+    one = solvers.resolve_wave(problem, st, [1, 2], **kw)
+    def eager(problem, aux, key, target, n_target, *rest, **kw):
+        return _eager_resolve_anneal(problem, aux, key,
+                                     np.asarray(target)[:n_target], *rest,
+                                     **kw)[0]
+    monkeypatch.setattr(solvers, "_resolve_anneal", eager)
+    eager = solvers.resolve_wave(problem, st, [1, 2], **kw)
+    np.testing.assert_array_equal(one.X, eager.X)
+    assert one.history == eager.history
+    assert one.objective == eager.objective

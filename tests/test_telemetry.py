@@ -178,9 +178,14 @@ RESOLVE_PHASES = ("prep", "targeted", "anneal", "select", "polish",
                   "result")
 
 
+# the host event of one XLA program launch on the CPU backend
+LAUNCH = "PjRtCpuExecutable::Execute"
+
+
 def _profiled(trace_dir, fn):
     """Run ``fn`` under the JAX profiler and return the host plane's
-    ``repro.*`` events: {name: [(start_ns, end_ns), ...]}."""
+    ``repro.*`` events and program launches (under ``LAUNCH``):
+    {name: [(start_ns, end_ns), ...]}."""
     import jax
     from jax.profiler import ProfileData
     opts = jax.profiler.ProfileOptions()
@@ -198,7 +203,7 @@ def _profiled(trace_dir, fn):
             continue
         for line in plane.lines:
             for ev in line.events:
-                if ev.name.startswith("repro."):
+                if ev.name.startswith("repro.") or ev.name == LAUNCH:
                     a = float(ev.start_ns)
                     spans.setdefault(ev.name, []).append(
                         (a, a + float(ev.duration_ns)))
@@ -236,14 +241,14 @@ def wave_trace(topo, tmp_path_factory):
             waves.append(sess.stats[-1].method)
 
     spans = _profiled(trace_dir, both)
-    return spans, waves
+    return spans, waves, tel
 
 
 def test_wave_phase_spans_nest_on_profiler_clock(wave_trace):
     """Every span is also a ``repro.<name>`` profiler annotation:
     ``apply_wave`` holds ``resolve_wave``, which holds the six resolve
     phases, and the wave's own phases lie inside ``apply_wave``."""
-    spans, waves = wave_trace
+    spans, waves, _ = wave_trace
     assert len(spans["repro.apply_wave"]) == 2
     assert len(spans["repro.resolve_wave"]) == 2
     _inside(spans, "repro.resolve_wave", "repro.apply_wave")
@@ -266,7 +271,7 @@ def test_wave_phase_spans_nest_on_profiler_clock(wave_trace):
 def test_full_solve_wave_spans_portfolio_phases(wave_trace):
     """The wave that makes a full solve due holds ``wave.full_solve``,
     which holds the portfolio's phases, and commits after it."""
-    spans, waves = wave_trace
+    spans, waves, _ = wave_trace
     assert waves[0] == "wave" and waves[1] != "wave"
     full, = spans["repro.wave.full_solve"]
     second = spans["repro.apply_wave"][1]
@@ -275,6 +280,39 @@ def test_full_solve_wave_spans_portfolio_phases(wave_trace):
     _inside(spans, "repro.portfolio.anneal", "repro.wave.full_solve")
     commit = spans["repro.wave.commit"][1]
     assert full[1] <= commit[0]
+
+
+ANNEAL_SPANS = ("repro.resolve.anneal", "repro.portfolio.anneal")
+
+
+def test_each_anneal_span_launches_one_program(wave_trace):
+    """Each anneal -- the wave's re-solve and the full solve's portfolio
+    anneal -- is one XLA program launch on the profiler's trace: its
+    chains' start, proposal draws, chains and best-chain pick no longer
+    dispatch op by op."""
+    spans, _, _ = wave_trace
+    launches = spans[LAUNCH]
+    assert len(spans["repro.resolve.anneal"]) == 2
+    assert len(spans["repro.portfolio.anneal"]) == 1
+    for name in ANNEAL_SPANS:
+        for a, b in spans[name]:
+            inside = [x for x, y in launches if a <= x and y <= b]
+            assert len(inside) == 1, (name, len(inside))
+
+
+def test_anneal_program_counter_matches_spans(wave_trace):
+    """``solver.anneal_programs`` counts one program per anneal span over
+    the session's life (its first solve, the untraced wave and the two
+    traced ones)."""
+    _, _, tel = wave_trace
+    programs = {k: v for k, v in tel.counters.items()
+                if k.startswith("solver.anneal_programs")}
+    n_spans = sum(tel.hists[f"span.{name[len('repro.'):]}.ms"].count
+                  for name in ANNEAL_SPANS)
+    assert sum(programs.values()) == n_spans >= 5
+    assert set(programs) == {
+        "solver.anneal_programs{backend=delta,site=portfolio}",
+        "solver.anneal_programs{backend=delta,site=resolve}"}
 
 
 def test_span_annotation_closes_on_exception(tmp_path):
