@@ -1,21 +1,20 @@
-"""Seeded inputs: services, the open-loop event trace and the bootstrap.
+"""Seeded inputs shared by every deployment: the services' record, the
+event record, due times, the wave cut and the greedy bootstrap.
 
-Every generator here reads only its arguments and a numpy ``Generator``,
-so the same seed gives the same services, the same due times and the same
-victims.  Nothing here imports the program.
+Every generator here, and under ``fleets/`` and ``streams/``, reads only
+its arguments and a numpy ``Generator``, so the same seed gives the same
+services, the same due times and the same departures.  Nothing here
+imports the program.
 
-* ``services``: the paper's VSR generator (arXiv:2105.03221 section 3):
-  VM 0 is the input VM at the service's source with U(0.1, 1) GFLOPS, the
-  other VMs U(3, 10) GFLOPS, chained by U(5, 50) Mbps virtual links.
-* ``replace_pairs``: Poisson "replace pairs" at a fixed rate.  Each pair is
-  one departure of a uniformly random live service and one arrival of a fresh service, due at the same
-  instant, so the live count never moves.
-  Every seed gets the same number of pairs and the same multiset of gaps
-  (exponential quantiles at the rate) in its own order: the seed changes
-  which gaps come when and which services leave, not how much work the
-  window holds.
-* ``cut_wave``: the serving loop's wave cut: every due pair up to the cap,
-  cut before a departure of a service that arrives in the same wave.
+* ``Services``: the benchmark's own record of a pool of services, the
+  rectangular arrays that a fleet generator (``fleets/<name>.py``) returns.
+* ``due_times``: the exponential quantiles of a Poisson process in an
+  order drawn from the seed: every seed gets the same gaps.
+* ``Event``: one due event of a stream (``streams/<name>.py``): an
+  arrival, a departure, or both as a replace pair.
+* ``cut_wave``: the serving loop's wave cut: every due event up to the
+  cap, cut before a departure of a service that arrives in the same wave.
+* ``live_counts``: the live counts that a trace passes through.
 * ``greedy_bootstrap``: the load-balanced greedy start over the serving
   tiers that the churn benchmarks of the program use: each VM on the least
   loaded fog or cloud node by accumulated GFLOPS.
@@ -23,7 +22,7 @@ victims.  Nothing here imports the program.
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import List, Sequence
+from typing import List, Optional, Sequence
 
 import numpy as np
 
@@ -38,18 +37,6 @@ class Services:
         return self.F.shape[0]
 
 
-def services(rng: np.random.Generator, n: int, n_vms: int,
-             sources: Sequence[int], vm_gflops=(3.0, 10.0),
-             input_gflops=(0.1, 1.0), link_mbps=(5.0, 50.0)) -> Services:
-    F = rng.uniform(*vm_gflops, size=(n, n_vms)).astype(np.float32)
-    F[:, 0] = rng.uniform(*input_gflops, size=n)
-    H = np.zeros((n, n_vms, n_vms), np.float32)
-    for v in range(n_vms - 1):
-        H[:, v, v + 1] = rng.uniform(*link_mbps, size=n)
-    src = np.asarray(rng.choice(np.asarray(sources), size=n), np.int32)
-    return Services(F=F, H=H, src=src)
-
-
 def due_times(rng: np.random.Generator, n: int, seconds: float) -> np.ndarray:
     """n due times in (0, seconds): the gaps are the exponential quantiles
     (i + 1/2) / n of a Poisson process with n events in ``seconds``, in an
@@ -61,39 +48,45 @@ def due_times(rng: np.random.Generator, n: int, seconds: float) -> np.ndarray:
 
 
 @dataclass
-class Pair:
-    due: float         # seconds after the window opens
-    victim: int        # sid of the departing service
-    sid: int           # sid of the arriving service (its row in the pool)
+class Event:
+    due: float                      # seconds after the window opens
+    victim: Optional[int] = None    # sid of the departing service
+    sid: Optional[int] = None       # sid of the arriving service (pool row)
+
+    @property
+    def size(self) -> int:
+        """Events it counts for: a replace pair is two."""
+        return (self.victim is not None) + (self.sid is not None)
 
 
-def replace_pairs(rng: np.random.Generator, n: int, seconds: float,
-                  live: Sequence[int], first_sid: int) -> List[Pair]:
-    """n replace pairs due in (0, seconds); each victim is drawn uniformly
-    from the live set as the pairs before it leave it.  Arrival sids count
-    up from ``first_sid``."""
-    live = list(live)
-    out = []
-    for k, t in enumerate(due_times(rng, n, seconds)):
-        victim = live.pop(int(rng.integers(len(live))))
-        sid = first_sid + k
-        live.append(sid)
-        out.append(Pair(float(t), victim, sid))
-    return out
-
-
-def cut_wave(pairs: Sequence[Pair], start: int, now: float,
-             cap_pairs: int) -> int:
-    """End index of the wave that starts at ``pairs[start]``: every pair
-    due by ``now``, at most ``cap_pairs``, cut before a pair whose
-    departure is a service that arrives earlier in the same wave."""
+def cut_wave(events: Sequence[Event], start: int, now: float,
+             cap_events: int) -> int:
+    """End index of the wave that starts at ``events[start]``: every event
+    due by ``now``, at most ``cap_events`` events (a replace pair counts
+    two), cut before an event whose departure is a service that arrives
+    earlier in the same wave."""
     arriving = set()
-    j = start
-    while (j < len(pairs) and j - start < cap_pairs
-           and pairs[j].due <= now and pairs[j].victim not in arriving):
-        arriving.add(pairs[j].sid)
+    j, n = start, 0
+    while j < len(events):
+        e = events[j]
+        if (e.due > now or n + e.size > cap_events
+                or e.victim in arriving):
+            break
+        if e.sid is not None:
+            arriving.add(e.sid)
+        n += e.size
         j += 1
     return j
+
+
+def live_counts(events: Sequence[Event], n_live: int) -> List[int]:
+    """The live count at the start and after each event of a trace, every
+    arrival admitted."""
+    out, n = [n_live], n_live
+    for e in events:
+        n += (e.sid is not None) - (e.victim is not None)
+        out.append(n)
+    return out
 
 
 def greedy_bootstrap(F: np.ndarray, hosts: Sequence[int]) -> np.ndarray:

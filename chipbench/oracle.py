@@ -6,7 +6,11 @@ Nothing here imports the program.  The substrate comes from a builder under
 it; routes come from a breadth-first search that visits neighbours in
 wiring order, as the program's router does.  A problem is the benchmark's
 own record of the live services: F [R, V] GFLOPS, H [R, V, V] Mbps and the
-source node of each service's input VM (VM 0).
+source node of each service's input VM (VM 0).  H may hold any graph over a
+service's VMs (a chain, a fan-out, a DAG): the links are its nonzero
+entries.  Services of fewer VMs are zero-padded to V, and a padding VM, with
+no demand and no link, changes no objective wherever it is placed, here as
+in the program; ``run.check`` takes any such pool.
 
 ``objectives`` scores a stack of placements and ``objective`` one;
 ``polish_residual`` reads what the best single move of one VM would still

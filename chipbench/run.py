@@ -6,30 +6,62 @@
 
 A cell is one entry of ``workloads`` in ``BENCHMARK.json``: a configuration
 (``chipbench/configs/<config>.json``: substrate, live fleet, placement spec,
-bootstrap) under a traffic mix (``chipbench/traffic/<mix>.json``: rate,
-wave cap, defrag-tick cadence).  In one process, in order:
+bootstrap) under a traffic mix (``chipbench/traffic/<mix>.json``: its
+stream, rate, wave cap, defrag-tick cadence).  In one process, in order:
 
-1. build the configuration on the program's normal path (``CFNSession``);
+1. draw the run's inputs from the seed (``plan``): the pool of services
+   from the configuration's fleet generator, the warm-up waves and the
+   window's due-ordered events from the mix's stream; build the
+   configuration on the program's normal path (``CFNSession``);
 2. bootstrap the live fleet (one full solve, or adopt a greedy placement);
-3. warm up exactly the cell's compile buckets: replace waves of 1, 2, 4,
-   ... pairs up to the cap, plus a ``defrag_tick`` where the mix runs one;
-4. serve an open-loop event stream for ``--seconds``: seeded Poisson
-   replace pairs, due times fixed before the window opens; whenever the
-   loop is free it takes every due event into one ``apply_wave``, up to
-   the cap, and sleeps until the next due time when nothing is due;
+3. warm up exactly the cell's compile buckets: the stream's warm-up waves
+   (every wave bucket and row bucket its trace visits), plus a
+   ``defrag_tick`` where the mix runs one;
+4. serve the open-loop event stream for ``--seconds``, due times fixed
+   before the window opens: whenever the loop is free it takes every due
+   event into one ``apply_wave``, up to the cap (a replace pair counts
+   two), cut before the departure of a service that arrives in the same
+   wave, and sleeps until the next due time when nothing is due.  The
+   departure of a service refused at admission is dropped and counted;
 5. check what the window committed against the float64 reference
    (``oracle.py``), built from the benchmark's own record of the live set;
 6. print one JSON line: ``correct``, ``attempted``, ``failed``,
    ``metrics``, ``device`` (and ``breakdown`` with ``--trace 1``), and last
    ``checks``: each number compared, beside its limit.
 
+A deployment is files that the harness finds by name, so a new one needs no
+edit to a file that is here:
+
+* a fleet generator ``chipbench/fleets/<name>.py``, named by the
+  configuration's ``fleet.generator`` (default ``paper_chains``), exports
+  ``services(rng, n, sources, **fleet["args"]) -> gen.Services``:
+  rectangular ``F [n, V]``, ``H [n, V, V]``, ``src [n]``, a smaller service
+  zero-padded (a zero-demand VM with no link changes no objective, in the
+  program or in ``oracle.py``; ``H`` may be any DAG).  It imports numpy and
+  ``gen`` and nothing of the program (``repro``);
+* a stream ``chipbench/streams/<name>.py``, named by the mix's ``kind``
+  (default ``replace_pairs``), exports ``events(rng, seconds, live,
+  first_sid, **traffic) -> [gen.Event]`` (due-ordered arrivals,
+  departures and replace pairs; arrival sids count up from ``first_sid``;
+  the live count along it depends on how many services are live, not on
+  which) and ``warm_plan(rng, live, first_sid, cap_events, counts,
+  **traffic) -> [(arriving sids, departing sids)]``: warm-up waves that
+  reach every wave and row bucket of a trace whose live counts are
+  ``counts``, ending at the count they start from;
+* a configuration and a mix (the JSON files above), and for each new
+  per-layer metric a reader ``chipbench/metrics/<name>.py`` (or ``<name up
+  to its first dot>.py``) whose ``read(run)`` takes the metric from the
+  reduced trace (``run.trace``: device time, the harness's annotations and
+  the program's ``repro.*`` spans), the telemetry counters of the window
+  (``run.counters``) and the run's records, and gives None where there is
+  nothing to read.
+
 An event's latency runs from its due time to the return of the
 ``apply_wave`` that committed it, so it counts the wait behind earlier
 waves.  With ``--trace 0`` the metrics are the cell's end-to-end metrics;
-with ``--trace 1`` the window runs under the profiler and the metrics are
-the cell's per-layer metrics, each read by ``chipbench/metrics/<name>.py``
-(or ``<name up to its first dot>.py``) from the reduced trace and the run's
-records.
+with ``--trace 1`` the program's telemetry is attached, the window's last
+``trace_seconds`` run under the profiler, and the metrics are the cell's
+per-layer metrics.
 
 The benchmark runs only on a TPU: with no TPU, or fewer chips than the
 cell asks for, it exits 3 and prints no result.  JAX's persistent
@@ -43,6 +75,7 @@ T_PROCESS = time.perf_counter()
 
 import argparse  # noqa: E402
 import contextlib  # noqa: E402
+import copy  # noqa: E402
 import importlib.util  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
@@ -103,6 +136,10 @@ class Run:
     check_seed: int = 0                     # draws the check's sample
     commits: List[Commit] = field(default_factory=list)
     pool: Optional[gen.Services] = None
+    # the program's telemetry counters, window close less window open
+    # (``--trace 1`` runs only)
+    counters: Dict[str, float] = field(default_factory=dict)
+    live_range: tuple = (0, 0)              # least and most live, planned
 
 
 class CompileCounter:
@@ -148,18 +185,27 @@ def cell_metrics(bench: dict, cell: str, trace: bool) -> List[dict]:
                              else [])]
 
 
+def load(kind: str, name: str):
+    """The module ``<kind>/<name>.py`` of the benchmark: a fleet generator
+    (``fleets``), an event stream (``streams``) or a metric reader
+    (``metrics``), found by its name."""
+    path = HERE / kind / f"{name}.py"
+    if not path.is_file():
+        raise FileNotFoundError(f"no {path}")
+    spec = importlib.util.spec_from_file_location(
+        f"chipbench_{kind}_{name.replace('.', '_')}", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
 def reader(name: str):
     """``metrics/<name>.py``, else ``metrics/<name up to its first dot>.py``:
     its ``read(run)`` gives the value, or None where there is nothing to
     read."""
     for stem in (name, name.split(".")[0]):
-        path = HERE / "metrics" / f"{stem}.py"
-        if path.is_file():
-            spec = importlib.util.spec_from_file_location(
-                f"metric_{stem.replace('.', '_')}", path)
-            mod = importlib.util.module_from_spec(spec)
-            spec.loader.exec_module(mod)
-            return mod.read
+        if (HERE / "metrics" / f"{stem}.py").is_file():
+            return load("metrics", stem).read
     raise FileNotFoundError(f"no reader for metric {name!r}")
 
 
@@ -168,15 +214,60 @@ def _seeds(seed: int, n: int) -> List[np.random.Generator]:
             for s in np.random.SeedSequence(seed).spawn(n)]
 
 
-def _warm_pairs(cap_pairs: int, n_live: int) -> List[int]:
-    """Replace-wave sizes, in pairs, that reach every wave bucket the
-    window can use: 1, 2, 4, ... and the largest wave possible."""
-    top = min(cap_pairs, n_live)
-    ks, k = [], 1
-    while k < top:
-        ks.append(k)
-        k *= 2
-    return ks + [top]
+@dataclass
+class Plan:
+    """A run's inputs, drawn from its seed before the program starts."""
+    pool: gen.Services          # every service, bootstrap first, by sid
+    boot: List[int]             # sids of the bootstrapped live fleet
+    warm: List[tuple]           # warm-up waves: (arriving, departing sids)
+    events: List[gen.Event]     # the window's trace, in due order
+    counts: List[int]           # live counts along the trace
+
+
+def _layers(cfg: dict) -> List[str]:
+    return oracle.node_layers(cfg["substrate"]["preset"],
+                              cfg["substrate"]["args"])[1]
+
+
+def plan(cfg: dict, traffic: dict, seed: int, seconds: float) -> Plan:
+    """The pool from the configuration's fleet generator, and the warm-up
+    and the window's events from the mix's stream (``kind``, by default
+    ``replace_pairs``).  The stream's warm-up plan is given the live counts
+    its trace will pass through, read from a copy of the trace's generator
+    before the warm-up: they depend on how many services are live, not on
+    which, and the warm-up ends at the count it started from."""
+    rng_fleet, rng_warm, rng_trace = _seeds(seed, 5)[:3]
+    fleet = cfg["fleet"]
+    stream = load("streams", traffic.get("kind", "replace_pairs"))
+    n_live = int(fleet["live"])
+    boot = list(range(n_live))
+    counts = gen.live_counts(stream.events(copy.deepcopy(rng_trace), seconds,
+                                           boot, n_live, **traffic), n_live)
+    warm = stream.warm_plan(rng_warm, boot, n_live,
+                            int(traffic["wave_cap_events"]), counts,
+                            **traffic)
+    live, nxt = set(boot), n_live
+    for arrive, depart in warm:
+        live.difference_update(depart)
+        live.update(arrive)
+        nxt = max([nxt] + [s + 1 for s in arrive])
+    if len(live) != n_live:
+        raise ValueError(f"the warm-up ends at {len(live)} live services, "
+                         f"not the {n_live} it started from")
+    events = stream.events(rng_trace, seconds, sorted(live), nxt, **traffic)
+    if gen.live_counts(events, n_live) != counts:
+        raise ValueError("the stream's live counts depend on which services "
+                         "are live")
+    n = max([nxt] + [e.sid + 1 for e in events if e.sid is not None])
+    pick = fleet["sources"]
+    layer_nodes = [i for i, t in enumerate(_layers(cfg))
+                   if t == pick["layer"]]
+    sources = layer_nodes[:max(pick.get("min", 1),
+                               int(len(layer_nodes) * pick["fraction"]))]
+    pool = load("fleets", fleet.get("generator", "paper_chains")).services(
+        rng_fleet, n, sources, **fleet.get("args", {}))
+    return Plan(pool=pool, boot=boot, warm=warm, events=events,
+                counts=counts)
 
 
 def serve(cfg: dict, traffic: dict, seed: int, seconds: float,
@@ -189,47 +280,46 @@ def serve(cfg: dict, traffic: dict, seed: int, seconds: float,
     from repro.core import solvers, topology, vsr
 
     counter = CompileCounter(jax)
-    rng_fleet, rng_warm, rng_trace, rng_key, rng_check = _seeds(seed, 5)
+    p = plan(cfg, traffic, seed, seconds)
+    rng_key, rng_check = _seeds(seed, 5)[3:]
+    pool, events = p.pool, p.events
 
     # 1. configuration
-    sub_args = cfg["substrate"]["args"]
-    topo = getattr(topology, cfg["substrate"]["preset"])(**sub_args)
-    _, layers = oracle.node_layers(cfg["substrate"]["preset"], sub_args)
-    fleet = cfg["fleet"]
-    pick = fleet["sources"]
-    layer_nodes = [i for i, t in enumerate(layers) if t == pick["layer"]]
-    sources = layer_nodes[:max(pick.get("min", 1),
-                               int(len(layer_nodes) * pick["fraction"]))]
-    n_live, n_vms = fleet["live"], fleet["n_vms"]
-    rate = float(traffic["rate_events_per_s"])
-    cap_pairs = int(traffic["wave_cap_events"]) // 2
-    tick_every = int(traffic.get("defrag_tick_every_events", 0))
-    n_pairs = max(1, int(round(rate * seconds / 2)))
-    warm = _warm_pairs(cap_pairs, n_live)
-    pool = gen.services(rng_fleet, n_live + sum(warm) + n_pairs, n_vms,
-                        sources)
+    topo = getattr(topology, cfg["substrate"]["preset"])(
+        **cfg["substrate"]["args"])
     svc = [vsr.VSRBatch(F=pool.F[i:i + 1], H=pool.H[i:i + 1],
                         src=pool.src[i:i + 1],
                         input_vm=np.zeros(1, np.int32))
            for i in range(len(pool))]
+    cap_events = int(traffic["wave_cap_events"])
+    tick_every = int(traffic.get("defrag_tick_every_events", 0))
     spec = PlacementSpec(**cfg["spec"])
     key = jax.random.PRNGKey(int(rng_key.integers(2 ** 31 - 1)))
     s = CFNSession(topo, spec, key=key)
+    tel = None
+    if trace_dir:
+        # the program's spans (``repro.*`` on the profiler's clock) and
+        # counters, in traced runs only
+        from repro.telemetry import Telemetry
+        tel = Telemetry(convergence=False)
+        s.attach_telemetry(tel)
     if session_hook is not None:
         session_hook(s)
 
     # 2. bootstrap
-    live = set(range(n_live))
+    live = set(p.boot)
+    boot = [svc[i] for i in p.boot]
     if cfg["bootstrap"] == "greedy":
+        layers = _layers(cfg)
         hosts = [i for tag in cfg["greedy_hosts"]
                  for i, t in enumerate(layers) if t == tag]
-        X0 = gen.greedy_bootstrap(pool.F[:n_live], hosts)
-        s.engine.bootstrap(svc[:n_live], X0=X0)
+        X0 = gen.greedy_bootstrap(pool.F[p.boot], hosts)
+        s.engine.bootstrap(boot, X0=X0)
     else:
-        s.engine.bootstrap(svc[:n_live])
+        s.engine.bootstrap(boot)
 
     commits: List[Commit] = []
-    counts = dict(attempted=0, failed=0, errors=0, unserved=0)
+    counts = dict(attempted=0, failed=0, errors=0, unserved=0, dropped=0)
 
     def record(t: float) -> None:
         res = s.result
@@ -245,20 +335,16 @@ def serve(cfg: dict, traffic: dict, seed: int, seconds: float,
         live.update(wr.admitted)
         return len(arrive) - len(wr.admitted)
 
-    # 3. warm-up: every wave bucket the window can use, and a defrag tick
-    next_sid = n_live
-    for k in warm:
-        depart = [int(x) for x in
-                  rng_warm.choice(sorted(live), size=k, replace=False)]
-        wave(list(range(next_sid, next_sid + k)), depart)
-        next_sid += k
+    # 3. warm-up: every wave and row bucket the window can use, and a
+    # defrag tick
+    for arrive, depart in p.warm:
+        if wave(arrive, depart):
+            raise RuntimeError("the warm-up had an arrival refused")
     if tick_every:
         s.defrag_tick()
     traces0 = dict(solvers.TRACE_COUNTS)
 
     # 4. the window
-    pairs = gen.replace_pairs(rng_trace, n_pairs, seconds, sorted(live),
-                              next_sid)
     drain_s = float(traffic.get("drain_s", 0.0))
     lat: List[float] = []
     annotate = (jax.profiler.TraceAnnotation if trace_dir
@@ -271,13 +357,14 @@ def serve(cfg: dict, traffic: dict, seed: int, seconds: float,
     if trace_dir:
         shutil.rmtree(trace_dir, ignore_errors=True)
     record(0.0)
+    tel0 = dict(tel.counters) if tel is not None else {}
     counter.on = True
     clock = time.perf_counter
     t0 = clock()
     setup_s = t0 - T_PROCESS
     i, since_tick, t_end, tracing = 0, 0, 0.0, False
     with contextlib.ExitStack() as traced:
-        while i < len(pairs):
+        while i < len(events):
             now = clock() - t0
             if now >= seconds + drain_s:
                 break
@@ -288,32 +375,42 @@ def serve(cfg: dict, traffic: dict, seed: int, seconds: float,
                 jax.profiler.start_trace(trace_dir, profiler_options=opts)
                 traced.callback(jax.profiler.stop_trace)
                 traced.enter_context(annotate(WINDOW))
-            if pairs[i].due > now:
+            if events[i].due > now:
                 with annotate(WAIT):
-                    time.sleep(pairs[i].due - now)
+                    time.sleep(events[i].due - now)
                 continue
-            j = gen.cut_wave(pairs, i, now, cap_pairs)
-            batch = pairs[i:j]
-            arrive = [p.sid for p in batch]
+            j = gen.cut_wave(events, i, now, cap_events)
+            batch = events[i:j]
+            i = j
+            arrive = [e.sid for e in batch if e.sid is not None]
+            # the departure of a service refused at admission is dropped
+            depart = [e.victim for e in batch
+                      if e.victim is not None and e.victim in live]
+            n_events = len(arrive) + len(depart)
+            counts["dropped"] += sum(e.victim is not None
+                                     for e in batch) - len(depart)
+            if not n_events:
+                continue
             try:
                 with annotate(WAVE):
-                    refused = wave(arrive, [p.victim for p in batch])
+                    refused = wave(arrive, depart)
             except Exception:
                 traceback.print_exc()
                 counts["errors"] += 1
-                counts["attempted"] += 2 * len(batch)
-                counts["failed"] += 2 * len(batch)
+                counts["attempted"] += n_events
+                counts["failed"] += n_events
                 break
             t_end = clock() - t0
-            counts["attempted"] += 2 * len(batch)
+            counts["attempted"] += n_events
             counts["failed"] += refused
-            for p in batch:
+            gone = set(depart)
+            for e in batch:
                 # the departure, and the arrival where it was admitted
-                lat += [t_end - p.due] * (1 + (p.sid in live))
+                n = (e.victim in gone) + (e.sid is not None and e.sid in live)
+                lat += [t_end - e.due] * n
             record(t_end)
-            i = j
             if tick_every:
-                since_tick += 2 * len(batch)
+                since_tick += n_events
                 if since_tick >= tick_every:
                     since_tick -= tick_every
                     with annotate(TICK):
@@ -321,7 +418,7 @@ def serve(cfg: dict, traffic: dict, seed: int, seconds: float,
                     if res is not None:
                         record(clock() - t0)
     counter.on = False
-    counts["unserved"] = 2 * (len(pairs) - i)
+    counts["unserved"] = sum(e.size for e in events[i:])
     compiled = dict(counter.names)
     for name, n in solvers.TRACE_COUNTS.items():
         if n != traces0.get(name, 0):
@@ -331,7 +428,13 @@ def serve(cfg: dict, traffic: dict, seed: int, seconds: float,
               latencies_ms=1e3 * np.asarray(lat),
               committed=counts["attempted"] - counts["failed"],
               elapsed_s=max(seconds, t_end),
-              compiles=sum(compiled.values()), compiled=compiled)
+              compiles=sum(compiled.values()), compiled=compiled,
+              live_range=(min(p.counts), max(p.counts)))
+    if tel is not None:
+        run.counters = {k: v - tel0.get(k, 0)
+                        for k, v in tel.counters.items()
+                        if v != tel0.get(k, 0)}
+        tel.detach_traces()
     del s
 
     # 5. the check, against the benchmark's own record
@@ -432,26 +535,37 @@ def check(cfg: dict, pool: gen.Services, commits: List[Commit],
                 watts_ratios=ratios)
 
 
+# idle-gap labels of the harness's own annotations
+HARNESS_LABELS = {WAVE: "engine host work inside apply_wave",
+                  TICK: "defrag_tick host work",
+                  WAIT: "waiting for a due event"}
+
+
 def breakdown(red, window) -> dict:
     """The device programs that took most time, and the device's idle time
-    by what the host was doing in it."""
+    by what the host was doing in it: the innermost program span
+    (``repro.*``) that holds a gap's midpoint, else the harness's
+    annotation that holds it, else the loop's own bookkeeping."""
     import xplane as tr
     progs = sorted(red.programs.items(), key=lambda kv: -kv[1])[:10]
     a, b = window
-    label_spans = [(name, np.asarray(red.spans.get(name, []),
-                                     np.float64).reshape(-1, 2))
-                   for name in (WAVE, TICK, WAIT)]
+    gaps = tr.gaps(red.busy, a, b)
+    mids = gaps.mean(axis=1)            # sorted: the gaps are disjoint
+    what = np.full(len(gaps), "loop bookkeeping between waves", object)
+    spans = [(HARNESS_LABELS.get(name, name), s0, s1)
+             for name, iv in red.spans.items()
+             if name in HARNESS_LABELS or name.startswith(tr.PROGRAM)
+             for s0, s1 in iv]
+    # longest first, so that the innermost span holding a midpoint labels
+    # it last; a program span lies inside the harness's annotations
+    spans.sort(key=lambda sp: (sp[0] in HARNESS_LABELS.values(),
+                               sp[2] - sp[1]), reverse=True)
+    for label, s0, s1 in spans:
+        what[np.searchsorted(mids, s0):
+             np.searchsorted(mids, s1, side="right")] = label
     idle: Dict[str, list] = {}
-    for g0, g1 in tr.gaps(red.busy, a, b):
-        mid = 0.5 * (g0 + g1)
-        what = "loop bookkeeping between waves"
-        for name, sp in label_spans:
-            if len(sp) and ((sp[:, 0] <= mid) & (mid <= sp[:, 1])).any():
-                what = {WAVE: "engine host work inside apply_wave",
-                        TICK: "defrag_tick host work",
-                        WAIT: "waiting for a due event"}[name]
-                break
-        idle.setdefault(what, []).append((g1 - g0) * 1e-9)
+    for label, (g0, g1) in zip(what, gaps):
+        idle.setdefault(label, []).append((g1 - g0) * 1e-9)
     gaps_out = sorted(([f"{k}: {len(v)} gaps, longest {max(v) * 1e3:.3f} ms",
                         float(sum(v))] for k, v in idle.items()),
                       key=lambda e: -e[1])[:10]
@@ -521,6 +635,8 @@ def run_cell(bench: dict, cell: dict, cfg: dict, traffic: dict, seed: int,
               "device": device}
     result.update(out)
     result["info"] = {"unserved": counts["unserved"],
+                      "departures_dropped": counts["dropped"],
+                      "live_range": list(run.live_range),
                       "commits": checks["commits"],
                       "fused_commits": checks["fused_commits"],
                       "fused_gap": checks["fused_gap"],
@@ -529,6 +645,8 @@ def run_cell(bench: dict, cell: dict, cfg: dict, traffic: dict, seed: int,
                       "worst_commit": checks["worst_commit"],
                       "compiled_in_window": run.compiled,
                       "setup_s": run.setup_s}
+    if trace:
+        result["info"]["counters"] = run.counters
     result["checks"] = shown
     return result
 

@@ -185,3 +185,129 @@ def test_bare_directory_no_result(tmp_path):
                        cwd=tmp_path, env=env, capture_output=True,
                        text=True, timeout=300)
     assert p.returncode != 0 and p.stdout.strip() == ""
+
+
+def _churn_tiny():
+    """The mix ``paper_poisson_churn`` on ``paper`` at 5 arrivals/s with 4 s
+    lifetimes (still ~20 live), a full solve every 4 events."""
+    bench, cell, cfg, _ = run.load_cell(ROOT, "paper.steady")
+    cfg["spec"] = dict(cfg["spec"], defrag_every=4)
+    traffic = json.loads(
+        (ROOT / "chipbench/traffic/paper_poisson_churn.json").read_text())
+    traffic.update(arrivals_per_s=5.0, mean_lifetime_s=4.0,
+                   wave_cap_events=8)
+    return bench, cell, cfg, traffic
+
+
+@pytest.mark.parametrize("refuse", [False, True])
+def test_churn_run(refuse):
+    """A sound churn run is correct.  An arrival refused at admission fails
+    the run, and its departure, due later in the window, is dropped and
+    counted, not handed to the engine."""
+    bench, cell, cfg, traffic = _churn_tiny()
+    seed, seconds = 2 ** 32 + 21, 3.0
+    p = run.plan(cfg, traffic, seed, seconds)
+    departs = {e.victim for e in p.events if e.victim is not None}
+    victim = next(e.sid for e in p.events if e.sid in departs)
+
+    def hook(s):
+        real = s.apply_wave
+
+        def apply_wave(arrivals=(), departures=()):
+            assert victim not in departures
+            keep = [a for a in arrivals if a[1] != victim]
+            wr = real(keep, departures) if keep or departures else \
+                mock.Mock(admitted=[])
+            return wr
+        s.apply_wave = apply_wave
+
+    out = run.run_cell(bench, cell, cfg, traffic, seed, seconds, False,
+                       require_tpu=False, session_hook=hook if refuse
+                       else None)
+    assert out["info"]["live_range"][0] < 20 < out["info"]["live_range"][1]
+    if refuse:
+        assert out["failed"] == 1 and out["correct"] is False
+        assert out["info"]["departures_dropped"] == 1
+        assert out["checks"]["bookkeeping_errors"]["value"] == 0
+    else:
+        assert out["correct"] is True and out["failed"] == 0
+        assert out["info"]["departures_dropped"] == 0
+        assert out["info"]["compiled_in_window"]
+
+
+# a deployment that the benchmark did not have: services that are diamonds
+# with a zero-demand VM of padding, and a stream of replace pairs at even
+# gaps in which the oldest service leaves
+DIAMONDS = '''
+import numpy as np
+import gen
+
+
+def services(rng, n, sources, pad=1):
+    V = 4 + pad
+    F = np.zeros((n, V), np.float32)
+    F[:, 0] = rng.uniform(0.1, 1.0, size=n)
+    F[:, 1:4] = rng.uniform(3.0, 10.0, size=(n, 3))
+    H = np.zeros((n, V, V), np.float32)
+    for a, b in ((0, 1), (0, 2), (1, 3), (2, 3)):
+        H[:, a, b] = rng.uniform(5.0, 50.0, size=n)
+    src = np.asarray(rng.choice(np.asarray(sources), size=n), np.int32)
+    return gen.Services(F=F, H=H, src=src)
+'''
+FIFO = '''
+import gen
+
+
+def events(rng, seconds, live, first_sid, pairs_per_s, **_):
+    n = int(pairs_per_s * seconds)
+    live = sorted(live)
+    return [gen.Event((k + 0.5) * seconds / n, victim=live[k] if k <
+                      len(live) else first_sid + k - len(live),
+                      sid=first_sid + k) for k in range(n)]
+
+
+def warm_plan(rng, live, first_sid, cap_events, counts, **_):
+    live, waves, k = sorted(live), [], 1
+    while k <= min(cap_events // 2, len(live)):
+        waves.append((list(range(first_sid, first_sid + k)), live[:k]))
+        live = live[k:] + waves[-1][0]
+        first_sid += k
+        k *= 2
+    return waves
+'''
+
+
+def test_new_deployment_is_new_files_only(tmp_path):
+    """A fleet generator, a stream, a configuration and a mix added as new
+    files beside a copy of the benchmark run as a cell, with no edit to a
+    file the benchmark has."""
+    bench_dir = tmp_path / "chipbench"
+    for f in (ROOT / "chipbench").rglob("*"):
+        if f.is_file() and "__pycache__" not in f.parts:
+            dst = tmp_path / f.relative_to(ROOT)
+            dst.parent.mkdir(parents=True, exist_ok=True)
+            dst.write_bytes(f.read_bytes())
+    (bench_dir / "fleets" / "diamonds.py").write_text(DIAMONDS)
+    (bench_dir / "streams" / "fifo_pairs.py").write_text(FIFO)
+    cfg = json.loads((ROOT / "chipbench/configs/paper.json").read_text())
+    cfg["fleet"].update(live=12, generator="diamonds", args={"pad": 1})
+    cfg["spec"] = {"defrag_every": 4}
+    (bench_dir / "configs" / "diamonds.json").write_text(json.dumps(cfg))
+    (bench_dir / "traffic" / "fifo.json").write_text(json.dumps(
+        {"kind": "fifo_pairs", "pairs_per_s": 8, "wave_cap_events": 8}))
+    bench = json.loads((ROOT / "BENCHMARK.json").read_text())
+    bench["configs"].append(dict(bench["configs"][0], name="diamonds",
+                                 file="chipbench/configs/diamonds.json"))
+    bench["workloads"].append({"name": "diamonds.fifo", "config": "diamonds",
+                               "traffic": "fifo", "chips": 1, "why": "-"})
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
+    with mock.patch.object(run, "HERE", bench_dir):
+        bench, cell, cfg, traffic = run.load_cell(tmp_path, "diamonds.fifo")
+        p = run.plan(cfg, traffic, 5, 3.0)
+        assert p.pool.F.shape[1:] == (5,) and not p.pool.F[:, 4].any()
+        assert len(p.events) == 24 and len(p.warm) == 3
+        out = run.run_cell(bench, cell, cfg, traffic, 5, 3.0, False,
+                           require_tpu=False)
+    assert out["correct"] is True
+    assert out["attempted"] == 48 and out["failed"] == 0
+    assert out["info"]["live_range"] == [12, 12]

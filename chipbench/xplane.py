@@ -1,14 +1,16 @@
 """Reduction of one profiler trace (``.xplane.pb``) to what the per-layer
 metrics read: device busy intervals, device time per program, the Pallas
-kernels' events, and the benchmark's own host annotations, all on the
-trace's one clock (nanoseconds).
+kernels' events, the benchmark's own host annotations and the program's
+spans, all on the trace's one clock (nanoseconds).
 
 Device planes are ``/device:TPU:<n>``.  On each, the line ``XLA Ops``
 holds one event per operation that ran, named by its HLO text (a Pallas
 kernel is a ``custom-call``), and ``XLA Modules`` one per program run,
 named after the jitted function.  Host annotations are the
 ``jax.profiler.TraceAnnotation`` spans that the serving loop opens, found
-on any line of the host plane by name.
+on any line of the host plane by name; the program's own spans are the
+annotations whose names start with ``repro.`` (a span of its telemetry,
+``repro.<span name>``), all of them kept.
 """
 from __future__ import annotations
 
@@ -23,6 +25,7 @@ import numpy as np
 DEVICE_PLANE = re.compile(r"^/device:TPU:\d+$")
 OPS_LINE = "XLA Ops"
 PROGRAMS_LINE = "XLA Modules"
+PROGRAM = "repro."          # the prefix of the program's span annotations
 
 
 @dataclass
@@ -94,7 +97,8 @@ def find_xplane(trace_dir: str) -> str:
 
 def reduce(xplane: str, span_names) -> Reduced:
     """Read one trace (``.xplane.pb``, or gzipped as ``.xplane.pb.gz``);
-    ``span_names`` are the host annotations to keep."""
+    ``span_names`` are the host annotations to keep, besides every
+    program span."""
     import gzip
     from jax.profiler import ProfileData
     want = set(span_names)
@@ -127,9 +131,10 @@ def reduce(xplane: str, span_names) -> Reduced:
         elif plane.name.startswith("/host:"):
             for line in plane.lines:
                 for ev in line.events:
-                    if ev.name in want:
+                    if ev.name in want or ev.name.startswith(PROGRAM):
                         a = float(ev.start_ns)
-                        spans[ev.name].append((a, a + float(ev.duration_ns)))
+                        spans.setdefault(ev.name, []).append(
+                            (a, a + float(ev.duration_ns)))
     for v in spans.values():
         v.sort()
     return Reduced(busy_per_device=per_device, kernels=kernels,
