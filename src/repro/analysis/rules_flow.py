@@ -176,6 +176,9 @@ CACHE_CAPS: Dict[str, int] = {
     "resolve_anneal": 64,
     "anneal_program": 32,
     "fused_pick": 32,
+    # the warm starts' one program: per problem shape, mask or none, and
+    # history bucket (coordinate, fixed_layer and the portfolio share it)
+    "warm_start_program": 32,
 }
 DEFAULT_CACHE_CAP = 64
 

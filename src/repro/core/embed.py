@@ -57,7 +57,8 @@ def _embed(topo: CFNTopology, vsrs: VSRBatch, spec,
     elif m == "coordinate":
         cdc = topo.layer_indices("cdc")[0]
         X0 = np.full((problem.R, problem.V), cdc, dtype=np.int32)
-        res = solvers.coordinate(problem, X0, eligible=eligible)
+        res = solvers.coordinate(problem, X0, eligible=eligible,
+                                 telemetry=telemetry)
     elif m == "exhaustive":
         res = solvers.exhaustive(problem, eligible=eligible)
     elif m == "anneal":

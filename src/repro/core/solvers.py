@@ -163,56 +163,6 @@ def _result(problem: PlacementProblem, X, method: str,
 
 
 # ---------------------------------------------------------------------------
-# Fixed-layer baselines (paper Fig. 3 scenarios)
-# ---------------------------------------------------------------------------
-
-def fixed_layer(problem: PlacementProblem, topo: CFNTopology,
-                layer: str, spill_layer: str = "cdc") -> SolveResult:
-    """All non-input VMs at `layer`; first-fit-decreasing across that layer's
-    nodes honoring GFLOPS capacity; overflow spills to ``spill_layer``
-    (the paper's observed behaviour at 20 VSRs)."""
-    nodes = topo.layer_indices(layer)
-    spill = topo.layer_indices(spill_layer)
-    # host-side FFD accounting, never traced
-    cap = np.array([topo.proc_hw[p].cap_gflops * topo.proc_hw[p].n_servers
-                    for p in range(topo.P)],
-                   dtype=np.float64)  # tracelint: allow[CFN102]
-    load = np.zeros(topo.P)
-    F = np.asarray(problem.F)
-    fixed_mask = np.asarray(problem.fixed_mask)
-    fixed_node = np.asarray(problem.fixed_node)
-    R, V = F.shape
-    # account pinned input VMs first
-    for r in range(R):
-        for v in range(V):
-            if fixed_mask[r, v]:
-                load[fixed_node[r, v]] += F[r, v]
-    X = np.zeros((R, V), dtype=np.int32)
-    order = sorted(((r, v) for r in range(R) for v in range(V)
-                    if not fixed_mask[r, v]),
-                   key=lambda rv: -F[rv])
-    for (r, v) in order:
-        placed = False
-        for p in sorted(nodes, key=lambda p: load[p]):
-            if load[p] + F[r, v] <= cap[p] + 1e-9:
-                X[r, v] = p
-                load[p] += F[r, v]
-                placed = True
-                break
-        if not placed:
-            for p in sorted(spill, key=lambda p: load[p]):
-                if load[p] + F[r, v] <= cap[p] + 1e-9:
-                    X[r, v] = p
-                    load[p] += F[r, v]
-                    placed = True
-                    break
-        if not placed:  # genuinely infeasible; dump on first node
-            X[r, v] = nodes[0]
-            load[nodes[0]] += F[r, v]
-    return _result(problem, X, f"fixed:{layer}")
-
-
-# ---------------------------------------------------------------------------
 # Coordinate descent (exact single-VM moves, scored by the delta engine)
 # ---------------------------------------------------------------------------
 
@@ -279,6 +229,25 @@ def _project_eligible(problem: PlacementProblem, X,
     return proj, bool(bad.any())
 
 
+def _sweep_scan(problem: PlacementProblem, aux: PlacementAux,
+                state: PlacementState, positions: jnp.ndarray,
+                eligible: Optional[jnp.ndarray] = None):
+    """One pass over ``positions``, each VM moved to its best node, traced:
+    the sweep of ``_sweep`` and of every coordinate descent.  Returns the
+    state and each move's objective."""
+    def body(state, pos):
+        r, v = pos[0], pos[1]
+        obj_all = delta_sweep(problem, aux, state, r, v)
+        if eligible is not None:
+            obj_all = jnp.where(eligible[r], obj_all, _INELIGIBLE)
+        best = jnp.argmin(obj_all)
+        state = apply_move(problem, aux, state, r, v,
+                           best.astype(state.X.dtype))
+        return state, obj_all[best]
+
+    return jax.lax.scan(body, state, positions)
+
+
 @jax.jit
 @count_traces("sweep")
 def _sweep(problem: PlacementProblem, aux: PlacementAux,
@@ -292,50 +261,168 @@ def _sweep(problem: PlacementProblem, aux: PlacementAux,
     the SLA hop/eligibility constraint of embed_latency_bounded threaded
     into the sweep.  ``positions`` may contain repeated rows (shape-bucket
     padding): re-sweeping a VM is idempotent up to its own argmin."""
-    def body(state, pos):
-        r, v = pos[0], pos[1]
-        obj_all = delta_sweep(problem, aux, state, r, v)
-        if eligible is not None:
-            obj_all = jnp.where(eligible[r], obj_all, _INELIGIBLE)
-        best = jnp.argmin(obj_all)
-        state = apply_move(problem, aux, state, r, v,
-                           best.astype(state.X.dtype))
-        return state, obj_all[best]
-
-    state, objs = jax.lax.scan(body, state, positions)
+    state, objs = _sweep_scan(problem, aux, state, positions, eligible)
     return state, objs[-1]
+
+
+# ---------------------------------------------------------------------------
+# Warm starts: fixed-layer first fit and coordinate descent, one program
+# ---------------------------------------------------------------------------
+
+def _layer_inputs(topo: CFNTopology, layer: str, spill_layer: str):
+    """The first fit's host inputs: ``layers`` [2, P] bool (the layer's
+    nodes, then the spill layer's) and each node's GFLOPS capacity [P]."""
+    layers = np.zeros((2, topo.P), bool)
+    layers[0, topo.layer_indices(layer)] = True
+    layers[1, topo.layer_indices(spill_layer)] = True
+    cap = np.array([h.cap_gflops * h.n_servers for h in topo.proc_hw],
+                   np.float32)
+    return layers, cap
+
+
+def _first_fit(problem: PlacementProblem, aux: PlacementAux, layers, cap):
+    """First-fit-decreasing, traced: the free VMs by decreasing GFLOPS
+    (stable, so ties keep row-major order), each onto the least-loaded
+    node of ``layers[0]`` it fits on (ties to the lowest index), else of
+    ``layers[1]``, else onto the first node of ``layers[0]``.  Pinned VMs
+    load their nodes first; loads and the capacity test are float32.
+    Returns the [R, V] placement, pinned positions at node 0."""
+    F = problem.F.reshape(-1)
+    pinned = problem.fixed_mask.reshape(-1)
+    load0 = (jax.nn.one_hot(problem.fixed_node.reshape(-1), problem.P,
+                            dtype=jnp.float32)
+             * jnp.where(pinned, F, 0.0)[:, None]).sum(0)
+    order = aux.free_flat[jnp.argsort(-F[aux.free_flat], stable=True)]
+    fallback = jnp.argmax(layers[0]).astype(jnp.int32)
+
+    def place(i, carry):
+        X, load = carry
+        j = order[i]
+        fits = load + F[j] <= cap
+        ok = layers & fits[None]
+        best = jnp.argmin(jnp.where(ok, load[None], jnp.inf), axis=1)
+        p = jnp.where(ok[0].any(), best[0],
+                      jnp.where(ok[1].any(), best[1], fallback))
+        p = p.astype(jnp.int32)
+        return X.at[j].set(p), load.at[p].add(F[j])
+
+    X = jnp.zeros(F.shape, jnp.int32)
+    if order.shape[0]:  # else every VM is pinned
+        X, _ = jax.lax.fori_loop(0, order.shape[0], place, (X, load0))
+    return X.reshape(problem.R, problem.V)
+
+
+def _descend(problem: PlacementProblem, aux: PlacementAux, X, eligible,
+             max_sweeps, tol, n_hist: int):
+    """Coordinate descent from ``X``, traced: exact best-single-move
+    sweeps over every free VM, the state refreshed exactly once per sweep
+    (no float32 drift; an exact, monotone incumbent history), until the
+    incumbent gains less than ``tol`` in a sweep or ``max_sweeps`` ran.
+    A masked descent does not trust its start as incumbent.  Returns the
+    incumbent (pinned), the incumbent history (``[n_hist]``, zero past the
+    sweeps run) and the number of sweeps run."""
+    hist = jnp.zeros(n_hist, jnp.float32)
+    if aux.free_pos.shape[0] == 0:  # every VM pinned: nothing to move
+        return X, hist, jnp.int32(0)
+    state = init_state(problem, X)
+    best = state.obj if eligible is None else jnp.float32(jnp.inf)
+
+    def more(c):
+        _, _, _, _, n, done = c
+        return (n < max_sweeps) & ~done
+
+    def sweep(c):
+        state, best, best_X, hist, n, _ = c
+        state, _ = _sweep_scan(problem, aux, state, aux.free_pos, eligible)
+        state = init_state(problem, state.X)
+        done = (n > 0) & (hist[n - 1] - state.obj < tol)
+        better = state.obj < best
+        best = jnp.where(better, state.obj, best)
+        best_X = jnp.where(better, state.X, best_X)
+        return state, best, best_X, hist.at[n].set(best), n + 1, done
+
+    _, _, best_X, hist, n, _ = jax.lax.while_loop(
+        more, sweep,
+        (state, best, state.X, hist, jnp.int32(0), jnp.bool_(False)))
+    return best_X, hist, n
+
+
+@functools.partial(jax.jit, static_argnames=("n_hist",))
+@count_traces("warm_start_program")
+def _warm_start_program(problem: PlacementProblem, aux: PlacementAux, X0,
+                        eligible, layers, cap, max_sweeps, tol, *,
+                        n_hist: int):
+    """Two coordinate descents as one device program: from ``X0``, then
+    from the ``_first_fit`` of ``layers``/``cap``, one after the other;
+    ``max_sweeps`` [2] caps each (0 returns its start, pinned, as is).
+    Returns ``([(X, breakdown)] * 2, history, n_sweeps)``, the last two
+    stacked over the starts."""
+    starts = jnp.stack([jnp.asarray(X0, jnp.int32),
+                        _first_fit(problem, aux, layers, cap)])
+    bX, hist, n = jax.lax.map(
+        lambda s: _descend(problem, aux, s[0], eligible, s[1], tol, n_hist),
+        (starts, max_sweeps))
+    # each breakdown its own output: stacked, XLA would reduce both at once
+    # and round their sums otherwise than ``evaluate`` does alone
+    return [_finish(problem, bX[k]) for k in range(2)], hist, n
+
+
+def _tol32(tol: float) -> np.float32:
+    """The least float32 at or above ``tol``: a float32 gain ``g`` then
+    satisfies ``g < _tol32(tol)`` exactly when ``g < tol``."""
+    t = np.float32(tol)
+    return t if float(t) >= tol else np.nextafter(t, np.float32(np.inf))
+
+
+def _warm_starts(problem: PlacementProblem, aux: PlacementAux, X0, eligible,
+                 layers, cap, max_sweeps, tol: float = 1e-6,
+                 methods=("coordinate", "coordinate")) -> List[SolveResult]:
+    """Run ``_warm_start_program`` and read both results back at once."""
+    el_np, _, _ = _eligible_np(eligible)
+    out, hist, n = jax.device_get(_warm_start_program(
+        problem, aux, X0, el_np, layers, cap,
+        np.asarray(max_sweeps, np.int32), _tol32(tol),
+        n_hist=_pow2(max(max_sweeps), lo=16)))
+    return [SolveResult(X=X, breakdown=bd, method=m,
+                        history=[float(h) for h in hist[k, :n[k]]])
+            for k, ((X, bd), m) in enumerate(zip(out, methods))]
+
+
+def fixed_layer(problem: PlacementProblem, topo: CFNTopology,
+                layer: str, spill_layer: str = "cdc") -> SolveResult:
+    """All non-input VMs at `layer`; first-fit-decreasing across that layer's
+    nodes honoring GFLOPS capacity; overflow spills to ``spill_layer``
+    (the paper's observed behaviour at 20 VSRs).  The fit is
+    ``_first_fit``, run by the warm starts' program with no sweeps and
+    read once."""
+    layers, cap = _layer_inputs(topo, layer, spill_layer)
+    X0 = np.zeros((problem.R, problem.V), np.int32)
+    return _warm_starts(problem, build_aux(problem), X0, None, layers, cap,
+                        (0, 0), methods=(None, f"fixed:{layer}"))[1]
 
 
 def coordinate(problem: PlacementProblem, X0: np.ndarray,
                max_sweeps: int = 12, tol: float = 1e-6,
-               eligible: Optional[np.ndarray] = None) -> SolveResult:
+               eligible: Optional[np.ndarray] = None,
+               telemetry=None) -> SolveResult:
     """Exact best-single-move sweeps.  ``eligible`` [R, P] (optional) masks
     each service row's destination nodes in every sweep argmin; X0 need not
     satisfy the mask (the first sweep moves every free VM onto it, and the
-    incumbent is only ever taken from post-sweep states)."""
-    aux = build_aux(problem)
-    el_np, _, _ = _eligible_np(eligible)
-    el_j = None if el_np is None else jnp.asarray(el_np)
-    positions = jnp.asarray(np.asarray(aux.free_pos))
-    if positions.shape[0] == 0:  # every VM pinned: nothing to move
-        return _result(problem, jnp.asarray(X0, jnp.int32), "coordinate")
-    state = init_state(problem, jnp.asarray(X0, jnp.int32))
-    # a masked solve may not trust an (ineligible) warm start as incumbent
-    best_obj = float("inf") if el_np is not None else float(state.obj)
-    best_X = state.X
-    history: List[float] = []
-    for _ in range(max_sweeps):
-        state, _ = _sweep(problem, aux, state, positions, el_j)
-        # exact refresh once per sweep: kills float32 drift and yields an
-        # exact (incumbent-best, hence monotone) history
-        state = init_state(problem, state.X)
-        obj = float(state.obj)
-        if obj < best_obj:
-            best_obj, best_X = obj, state.X
-        history.append(best_obj)
-        if len(history) > 1 and history[-2] - obj < tol:
-            break
-    return _result(problem, best_X, "coordinate", history)
+    incumbent is only ever taken from post-sweep states).
+
+    The descent runs as one device program (``_warm_start_program``, its
+    second start given no sweeps), read back once.  ``telemetry``
+    (optional) counts ``solver.warm_start_programs{site=coordinate}`` and
+    the sweeps run, ``solver.coordinate_sweeps{site=coordinate}``."""
+    layers = np.zeros((2, problem.P), bool)
+    cap = np.zeros(problem.P, np.float32)
+    res = _warm_starts(problem, build_aux(problem), X0, eligible, layers,
+                       cap, (max_sweeps, 0), tol)[0]
+    if telemetry is not None:
+        telemetry.inc("solver.warm_start_programs", site="coordinate")
+        telemetry.inc("solver.coordinate_sweeps", len(res.history),
+                      site="coordinate")
+    return res
 
 
 # ---------------------------------------------------------------------------
@@ -532,7 +619,8 @@ def anneal(problem: PlacementProblem, key: jax.Array, X0: np.ndarray,
            backend: str = "auto",
            eligible: Optional[np.ndarray] = None,
            record_conv: bool = False,
-           telemetry=None) -> SolveResult:
+           telemetry=None,
+           aux: Optional[PlacementAux] = None) -> SolveResult:
     """Batched Metropolis chains on incremental (delta-evaluated) state.
 
     backend:
@@ -565,9 +653,11 @@ def anneal(problem: PlacementProblem, key: jax.Array, X0: np.ndarray,
     read back once; the fused backend launches its kernel between that
     program and ``_fused_pick``.  ``telemetry`` (optional) counts the
     programs launched: ``solver.anneal_programs{site=portfolio}``.
+    ``aux`` (optional) is the problem's ``build_aux``, where the caller
+    has it.
     """
     R, V, P = problem.R, problem.V, problem.P
-    aux = build_aux(problem)
+    aux = build_aux(problem) if aux is None else aux
     if backend == "auto":
         backend = auto_backend(jax.default_backend(), P, problem.N, R * V,
                                aux.inc_h.shape[1], n_steps)
@@ -1079,8 +1169,9 @@ def solve_portfolio(problem: PlacementProblem, topo: CFNTopology,
     is provably optimal; tests pin it to the exhaustive optimum.
 
     ``telemetry`` (optional) spans ``portfolio.warm_starts`` (both
-    ``coordinate`` candidates and ``fixed_layer``) and
-    ``portfolio.anneal``.
+    coordinate descents, one program) and ``portfolio.anneal``, and
+    counts ``solver.warm_start_programs{site=portfolio}`` and the sweeps
+    run, ``solver.coordinate_sweeps{site=portfolio}``.
     """
     key = jax.random.PRNGKey(0) if key is None else key
     effort = getattr(spec, "effort", "standard")
@@ -1088,14 +1179,20 @@ def solve_portfolio(problem: PlacementProblem, topo: CFNTopology,
     if eligible is None and spec is not None:
         eligible = spec.masks(problem)
     cdc = topo.layer_indices("cdc")[0]
-    candidates: List[SolveResult] = []
-    # warm starts: CDC-everything and IoT-first-fit (the masked coordinate
-    # sweeps project both onto the eligible set in their first pass)
+    # warm starts: CDC-everything and IoT-first-fit, both descended in one
+    # program (the masked sweeps move both onto the eligible set in their
+    # first pass)
     with span(telemetry, "portfolio.warm_starts"):
+        aux = build_aux(problem)
         base_cdc = np.full((problem.R, problem.V), cdc, dtype=np.int32)
-        candidates.append(coordinate(problem, base_cdc, eligible=eligible))
-        iot_ff = fixed_layer(problem, topo, "iot")
-        candidates.append(coordinate(problem, iot_ff.X, eligible=eligible))
+        layers, cap = _layer_inputs(topo, "iot", "cdc")
+        candidates = _warm_starts(problem, aux, base_cdc, eligible, layers,
+                                  cap, (12, 12))  # coordinate's max_sweeps
+        if telemetry is not None:
+            telemetry.inc("solver.warm_start_programs", site="portfolio")
+            telemetry.inc("solver.coordinate_sweeps",
+                          sum(len(c.history) for c in candidates),
+                          site="portfolio")
     if effort in ("standard", "high"):
         k1, k2 = jax.random.split(key)
         warm = min(candidates, key=lambda r: r.objective).X
@@ -1103,7 +1200,7 @@ def solve_portfolio(problem: PlacementProblem, topo: CFNTopology,
         with span(telemetry, "portfolio.anneal"):
             candidates.append(anneal(problem, k1, warm, n_steps=n_steps,
                                      backend=backend, eligible=eligible,
-                                     telemetry=telemetry))
+                                     telemetry=telemetry, aux=aux))
         if effort == "high":
             candidates.append(genetic(problem, k2, warm, eligible=eligible))
     best = min(candidates, key=lambda r: r.objective)

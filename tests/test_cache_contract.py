@@ -129,6 +129,38 @@ def test_anneal_programs_within_static_bounds(bounds):
     assert not any(_deltas(before).values()), _deltas(before)
 
 
+def test_warm_start_program_within_static_bound(bounds):
+    """The warm starts' program compiles once per problem shape: a full
+    solve compiles it once, a repeated solve with a new key compiles
+    nothing, and neither does a ``relax`` (its ``max_sweeps=4`` descent
+    runs the same program) nor a ``fixed_layer`` baseline on the same
+    problem.  The count sits inside the CFN108 scenario bound for the
+    ``_warm_starts`` site (one history bucket)."""
+    topo = topology.paper_topology()
+    # n_vms=8 is unique to this test: every compile below is fresh
+    vs = vsr.random_vsrs(3, rng=4, n_vms=8,
+                         source_nodes=topo.layer_indices("iot")[:2])
+    problem = power.build_problem(topo, vs)
+    spec = PlacementSpec(effort="quick")
+
+    before = dict(solvers.TRACE_COUNTS)
+    solvers.solve_portfolio(problem, topo, spec, jax.random.PRNGKey(0))
+    d = _deltas(before)
+    assert d.get("warm_start_program", 0) == 1
+    assert d.get("sweep", 0) == 0
+    eb = bounds["warm_start_program"]
+    cards = {name: 1 for name, ax in eb.axes().items()
+             if ax.kind == "bucket"}               # one history bucket
+    _check("warm_start_program", d["warm_start_program"],
+           eb.evaluate(sites=["_warm_starts"], axis_cards=cards))
+
+    before = dict(solvers.TRACE_COUNTS)
+    solvers.solve_portfolio(problem, topo, spec, jax.random.PRNGKey(1))
+    solvers.relax(problem, jax.random.PRNGKey(2), steps=4)
+    solvers.fixed_layer(problem, topo, "af")
+    assert not any(_deltas(before).values()), _deltas(before)
+
+
 def test_federated_solve_regions_within_static_bound(bounds):
     """Two same-bucket federated solves compile ``solve_regions`` once;
     the CFN108 scenario bound for the ``solve_portfolio_batched`` site

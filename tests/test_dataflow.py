@@ -279,7 +279,8 @@ def test_cfn108_shipped_bounds_under_caps():
     project, errs = load_project([str(REPO / "src")])
     assert not errs
     bounds = compute_cache_bounds(project)
-    for entry in ("sweep", "anneal_delta", "anneal_full", "solve_regions"):
+    for entry in ("sweep", "anneal_delta", "anneal_full", "solve_regions",
+                  "warm_start_program"):
         eb = bounds[entry]
         b = eb.static_bound()
         assert b is not None, f"{entry}: unbounded static provenance"

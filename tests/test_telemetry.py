@@ -315,6 +315,47 @@ def test_anneal_program_counter_matches_spans(wave_trace):
         "solver.anneal_programs{backend=delta,site=resolve}"}
 
 
+def test_warm_starts_span_launches_one_program(wave_trace):
+    """The full solve's warm starts -- both coordinate descents and the
+    IoT first fit -- are one XLA program launch on the profiler's trace,
+    and ``solver.warm_start_programs`` counts one program per
+    ``portfolio.warm_starts`` span over the session's life."""
+    spans, _, tel = wave_trace
+    launches = spans[LAUNCH]
+    (a, b), = spans["repro.portfolio.warm_starts"]
+    assert len([x for x, y in launches if a <= x and y <= b]) == 1
+    programs = {k: v for k, v in tel.counters.items()
+                if k.startswith("solver.warm_start_programs")}
+    n_spans = tel.hists["span.portfolio.warm_starts.ms"].count
+    assert programs == {
+        "solver.warm_start_programs{site=portfolio}": n_spans}
+    assert n_spans >= 2
+
+
+def test_coordinate_sweeps_counter_sums_histories(topo):
+    """``solver.coordinate_sweeps`` adds the sweeps each warm-start program
+    ran, read from the program's own count: the summed history lengths
+    of the descents, per site."""
+    problem = power.build_problem(topo, vsr.random_vsrs(
+        6, rng=np.random.default_rng(9), n_vms=3))
+    cdc = topo.layer_indices("cdc")[0]
+    base = np.full((problem.R, problem.V), cdc, np.int32)
+    ffd = solvers.fixed_layer(problem, topo, "iot").X
+    want = [len(solvers.coordinate(problem, X0).history)
+            for X0 in (base, ffd)]
+    tel = Telemetry()
+    solvers.solve_portfolio(problem, topo, PlacementSpec(effort="quick"),
+                            telemetry=tel)
+    res = solvers.coordinate(problem, base, max_sweeps=4, telemetry=tel)
+    assert {k: v for k, v in tel.counters.items()
+            if k.startswith("solver.")} == {
+        "solver.warm_start_programs{site=portfolio}": 1,
+        "solver.coordinate_sweeps{site=portfolio}": sum(want),
+        "solver.warm_start_programs{site=coordinate}": 1,
+        "solver.coordinate_sweeps{site=coordinate}": len(res.history)}
+    assert 1 < sum(want) and len(res.history) == min(4, want[0])
+
+
 def test_span_annotation_closes_on_exception(tmp_path):
     """A span that raises still closes its profiler annotation: the next
     span is its sibling on the trace, not its child."""
