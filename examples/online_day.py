@@ -82,7 +82,7 @@ for ev in events:
 
 n_events = len(lat)
 methods = [s.method for s in session.stats]
-n_inc = sum(1 for m in methods if m == "incremental")
+n_inc = sum(1 for m in methods if m == "wave")
 print(f"\nday done: {n_events} churn events in {time.time() - t_day:.1f}s "
       f"wall ({n_inc} incremental, {n_events - n_inc} full/defrag)")
 print(f"re-solve latency: median={np.median(lat) * 1e3:.1f}ms "

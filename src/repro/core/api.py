@@ -385,14 +385,15 @@ class CFNSession:
 
     def add(self, service: vsr_mod.VSRBatch, sid: Optional[int] = None,
             priority: Optional[int] = None) -> Optional[SolveResult]:
-        """Admit one service (R=1): warm-start incremental re-embedding
-        under the spec's masks and admission budgets.  ``priority`` is the
+        """Admit one service (R=1) as a wave of one: warm-start
+        re-embedding under the spec's masks and admission budgets.  ``priority`` is the
         admission class (0 = highest; < ``spec.priority_classes``).
         ``None`` = rejected."""
         return self._engine.add(service, sid=sid, priority=priority)
 
     def remove(self, sid: int) -> Optional[SolveResult]:
-        """Retire a service: detach its loads, re-settle survivors."""
+        """Retire a service as a wave of one: detach its loads, re-settle
+        survivors."""
         return self._engine.remove(sid)
 
     def apply_wave(self, arrivals: Sequence = (),
@@ -400,8 +401,8 @@ class CFNSession:
         """Apply one churn wave (a tick's arrivals + departures) as a
         single batched re-solve (``OnlineEmbedder.apply_wave``): one fused
         detach, one warm-started ``solvers.resolve_wave``, one polish pass,
-        priority-ordered admission, queue drain.  A wave of size 1 is
-        bit-identical to the per-event ``add``/``remove`` path."""
+        priority-ordered admission, queue drain.  ``add`` and ``remove``
+        run this same path as waves of one."""
         return self._engine.apply_wave(arrivals, departures)
 
     def defrag_tick(self, rows: Optional[int] = None) -> Optional[SolveResult]:

@@ -9,11 +9,12 @@ This module supplies both halves of that regime:
   * **Timelines** -- non-homogeneous Poisson arrivals (thinning) under a
     24 h diurnal rate profile, exponential service lifetimes, and scenario
     presets (`steady`, `diurnal24`, `burst`).
-  * **OnlineEmbedder** -- the live placement state machine: `add` / `remove`
-    carry the previous embedding through `power.warm_state` /
-    `power.detach_vsrs` and re-solve with `solvers.resolve_incremental`
-    (only the churned service's VMs are re-placed; survivors polish in
-    place).  Every `defrag_every` events a full portfolio solve
+  * **OnlineEmbedder** -- the live placement state machine: every churn
+    event is a wave (`apply_wave`; `add` / `remove` are waves of one) that
+    carries the previous embedding through `power.detach_vsrs` /
+    `power.warm_state` and re-solves with `solvers.resolve_wave` (only the
+    arrivals' VMs are re-placed; survivors polish in place).  Every
+    `defrag_every` events a full portfolio solve
     (`solvers.solve_portfolio`) re-packs the substrate and bounds the drift of
     purely local re-optimization.
 
@@ -353,7 +354,7 @@ def fault_preset(name: str, topo: CFNTopology, **kw) -> List[FaultEvent]:
 @dataclass
 class OnlineStats:
     """Bookkeeping for one engine event (exposed to benchmarks/examples)."""
-    event: str                 # "add" | "remove" | "defrag" | "reject"
+    event: str                 # "add" | "remove" | "wave" | "reject" | ...
     method: str
     objective: float
     power_w: float
@@ -407,11 +408,12 @@ class OnlineEmbedder:
     """Live CFN embedding under service churn.
 
     Keeps the current VSR set, placement, and incremental
-    ``PlacementState``; ``add`` / ``remove`` re-solve with
-    ``solvers.resolve_incremental`` (one-service warm-start re-embedding)
-    and every ``spec.defrag_every`` events -- or on demand via ``defrag()``
-    -- runs the full portfolio to re-pack the substrate.  Service identity
-    is the caller's ``sid``; internally rows are dense [0, R).
+    ``PlacementState``; every churn event is a wave (``apply_wave``, with
+    ``add`` / ``remove`` as waves of one) re-solved warm by
+    ``solvers.resolve_wave``, and every ``spec.defrag_every`` events -- or
+    on demand via ``defrag()`` -- runs the full portfolio to re-pack the
+    substrate.  Service identity is the caller's ``sid``; internally rows
+    are dense [0, R).
 
     Configuration lives in one declarative ``repro.api.PlacementSpec``
     (pass ``spec=``; the legacy kwarg signature is a deprecated shim that
@@ -730,12 +732,6 @@ class OnlineEmbedder:
         self._events_since_defrag = 0
         return res
 
-    def _carry_loads(self) -> Optional[tuple]:
-        if self._state is None:
-            return None
-        s = self._state
-        return (s.omega, s.tm, s.theta, s.lam)
-
     # -- the priority rejection queue -------------------------------------
     @property
     def queued_sids(self) -> List[int]:
@@ -843,20 +839,6 @@ class OnlineEmbedder:
                 "a scalar max_hops for churn, or positional constraints "
                 "with the static batch path (CFNSession.solve/defrag).")
 
-    def _admit_reason(self, res: solvers.SolveResult, prev_power: float,
-                      prev_violation: float) -> Optional[str]:
-        """SLA admission test on the solved arrival placement: ``None`` when
-        admissible, else the monitor counter kind naming the violated
-        budget."""
-        if (self.admit_power_budget_w is not None
-                and res.power - prev_power > self.admit_power_budget_w):
-            return "power_budget_exceeded"
-        if (self.admit_violation_tol is not None
-                and float(res.breakdown.violation) - prev_violation
-                > self.admit_violation_tol):
-            return "violation_budget_exceeded"
-        return None
-
     @property
     def _admission_active(self) -> bool:
         return (self.max_hops is not None
@@ -865,118 +847,22 @@ class OnlineEmbedder:
 
     @_traced("add")
     def add(self, service: vsr.VSRBatch, sid: Optional[int] = None,
-            priority: Optional[int] = None,
-            _retry: bool = False,
-            _qseq: Optional[int] = None) -> Optional[solvers.SolveResult]:
-        """Admit one service (an R=1 VSRBatch): warm-start incremental
-        re-embedding; the very first service (and every
-        ``defrag_every``-th event) takes the full-portfolio path -- except
-        under admission control, where even the first service goes through
-        the masked incremental path so the hop/budget contract holds.
+            priority: Optional[int] = None) -> Optional[solvers.SolveResult]:
+        """Admit one service (an R=1 VSRBatch) as a wave of one arrival.
 
         ``priority`` is the service's admission class (0 = most important;
-        must be < ``spec.priority_classes``).  With admission control
-        configured, returns ``None`` when the arrival is rejected (the
-        engine state is rolled back; with ``queue_rejected`` the service is
-        parked and retried after the next capacity-increasing event).
-        With ``spec.preempt``, a power-budget rejection may instead park a
-        strictly lower-class live service (lowest class, newest first) and
-        retry.  ``_retry`` marks a queue re-attempt: a re-rejection does
-        not re-increment the rejected/queued counters (they count distinct
-        arrivals) and re-parks the service at its original queue position
-        (``_qseq``), while an eventual success still counts as admitted."""
+        must be < ``spec.priority_classes``).  Returns the committed result,
+        or ``None`` when the arrival is not admitted: refused by admission
+        control (the engine state is rolled back; with ``queue_rejected``
+        the service is parked and retried after the next
+        capacity-increasing event) or parked because its source node is
+        down.  With ``spec.preempt``, a power-budget refusal may instead
+        park a strictly lower-class live service (lowest class, newest
+        first) and retry."""
         if service.R != 1:
             raise ValueError(f"add() takes one service, got R={service.R}")
-        self._check_churn_constraints("add")
-        prio = self._priority_of(priority)
-        if sid is None:
-            sid = self._next_sid
-        if sid in self._sids:
-            raise ValueError(f"sid {sid} is already live")
-        self._next_sid = max(self._next_sid, sid + 1)
-        h = self.spec.health
-        if h is not None and not bool(h.node_up[int(service.src[0])]):
-            # the service's pinned source node is down: a fault is not an
-            # SLA rejection, so the arrival is always parked (regardless of
-            # queue_rejected) and retried on recovery
-            self._park(service, sid, prio, seq=_qseq)
-            if not _retry:
-                self.admission["queued"] += 1
-                if self.monitor is not None:
-                    self.monitor.strand(sid, self._now,
-                                        detail=f"sid={sid} source down")
-            self.stats.append(OnlineStats(
-                event="strand", method="fault", objective=self.objective(),
-                power_w=self.power_w(), n_live=self.n_live))
-            return None
-        prev = (self._vsrs[:], self._sids[:], self._prio[:],
-                self._batch_cache, self._problem, self._X, self._state,
-                self._result, self._events_since_defrag)
-        prev_X, prev_loads = self._X, self._carry_loads()
-        self._vsrs.append(service)
-        self._sids.append(sid)
-        self._prio.append(prio)
-        self._batch_cache = (service if self._batch_cache is None
-                             else self._batch_cache.concat(service))
-        self._rebuild_problem()
-        self._events_since_defrag += 1
-        if prev_X is None and not self._admission_active:
-            res = self._full_solve("add")
-            self.admission["admitted"] += 1
-            return res
-        row = self.n_live - 1
-        if prev_X is None:
-            # empty engine under admission control: start from the pinned
-            # sources (an all-src placement) so the masked incremental
-            # path and the budget check below still apply
-            st = power.init_state(self._problem,
-                                  np.asarray(self._problem.fixed_node))
-            prev_power, prev_viol = 0.0, 0.0
-        else:
-            row_map = list(range(row)) + [-1] * (self._problem.R - row)
-            st = power.warm_state(self._problem, prev_X,
-                                  prev_loads=prev_loads, row_map=row_map)
-            prev_power = 0.0 if prev[7] is None else prev[7].power
-            prev_viol = (0.0 if prev[7] is None
-                         else float(prev[7].breakdown.violation))
-        res = solvers.resolve_incremental(
-            self._problem, key=self._split_key(),
-            changed_rows=[row], state=st, spec=self.spec,
-            **self._resolve_kw(self._add_kw))
-        reason = self._admit_reason(res, prev_power, prev_viol)
-        if reason is not None:
-            (self._vsrs, self._sids, self._prio, self._batch_cache,
-             self._problem, self._X, self._state, self._result,
-             self._events_since_defrag) = prev
-            if reason == "power_budget_exceeded" and self.spec.preempt:
-                victim = self._preempt_victim(prio)
-                if victim is not None:
-                    return self.add(service, sid=sid, priority=prio,
-                                    _retry=_retry, _qseq=_qseq)
-            if self.monitor is not None and not _retry:
-                # distinct arrivals only (queue re-tries would double-count
-                # against the engine's own admission['rejected'])
-                self.monitor.count("admission_rejected", detail=f"sid={sid}")
-                self.monitor.count(reason, detail=f"sid={sid}")
-            if not _retry:
-                self.admission["rejected"] += 1
-                if self.queue_rejected:
-                    self.admission["queued"] += 1
-            if self.queue_rejected or _retry:
-                self._park(service, sid, prio, seq=_qseq)
-            self.stats.append(OnlineStats(
-                event="reject", method="admission", objective=res.objective,
-                power_w=res.power, n_live=self.n_live))
-            return None
-        self.admission["admitted"] += 1
-        if self.monitor is not None:
-            # closes the availability window if this sid was stranded by a
-            # fault (no-op otherwise)
-            self.monitor.unstrand(sid, self._now)
-        if self._defrag_due():
-            return self._full_solve("add", incumbent=res)
-        self._commit(res, "add")
-        return res
+        wr = self._apply_wave([(service, sid, priority)], (), "add")
+        return wr.result if wr.admitted else None
 
     def _preempt_victim(self, prio: int) -> Optional[int]:
         """Park the lowest-class live service strictly below ``prio``
@@ -987,9 +873,10 @@ class OnlineEmbedder:
             return None
         r = max(victims, key=lambda i: (self._prio[i], i))
         vsid, vsvc, vprio = self._sids[r], self._vsrs[r], self._prio[r]
-        # no drain: the arrival that triggered this retries first, and a
-        # drain here would just re-admit the victim we parked
-        self.remove(vsid, _drain=False)
+        # no drain: the arrival that triggered this retries first, before
+        # any parked arrival can take the freed budget
+        self._wave([], [vsid], WaveResult(self._result, departed=[vsid]),
+                   drain=False)
         self._park(vsvc, vsid, vprio)
         self.admission["preempted"] += 1
         if self.monitor is not None:
@@ -1000,46 +887,12 @@ class OnlineEmbedder:
         return vsid
 
     @_traced("remove")
-    def remove(self, sid: int,
-               _drain: bool = True) -> Optional[solvers.SolveResult]:
-        """Retire a service: detach its loads in O(V*(N+P)), then let the
-        survivors re-settle with polish sweeps (no changed rows).  Freed
-        capacity re-admits queued arrivals (``queue_rejected``)."""
-        self._check_churn_constraints("remove")
-        row = self._sids.index(sid)
-        detached = power.detach_vsrs(self._problem, self._state, [row])
-        prev_X = self._X
-        surv = [i for i in range(self.n_live) if i != row]
-        del self._vsrs[row]
-        del self._sids[row]
-        del self._prio[row]
-        if not self._vsrs:
-            self._problem = self._X = self._state = self._result = None
-            self._batch_cache = None
-            self.stats.append(OnlineStats("remove", "empty", 0.0, 0.0, 0))
-            if _drain:
-                self._drain_queue()
-            return None
-        self._drop_row(row)
-        self._rebuild_problem()
-        self._events_since_defrag += 1
-        row_map = surv + [-1] * (self._problem.R - len(surv))
-        st = power.warm_state(
-            self._problem, prev_X,
-            prev_loads=(detached.omega, detached.tm, detached.theta,
-                        detached.lam),
-            row_map=row_map)
-        res = solvers.resolve_incremental(
-            self._problem, key=self._split_key(),
-            changed_rows=[], state=st, spec=self.spec,
-            **self._resolve_kw(self._remove_kw))
-        if self._defrag_due():
-            res = self._full_solve("remove", incumbent=res)
-        else:
-            self._commit(res, "remove")
-        if _drain:
-            self._drain_queue()
-        return res
+    def remove(self, sid: int) -> Optional[solvers.SolveResult]:
+        """Retire a service as a wave of one departure: its loads detach,
+        the survivors re-settle, and freed capacity re-admits queued
+        arrivals.  Returns the committed result, or ``None`` once the
+        engine is empty."""
+        return self._apply_wave((), [sid], "remove").result
 
     # -- wave-batched churn ------------------------------------------------
     @_traced("apply_wave")
@@ -1059,10 +912,17 @@ class OnlineEmbedder:
         per arrival in priority order, and a departure-carrying wave drains
         the rejection queue.
 
-        A wave of size 1 delegates verbatim to ``add``/``remove`` --
-        bit-identical placements, power, and admission counters -- so
-        per-event callers can migrate with no behavior change."""
-        self._check_churn_constraints("apply_wave")
+        This is the engine's one churn path: ``add`` and ``remove`` are
+        waves of one, and queue and preemption retries are waves of one
+        too.  Stats record a one-arrival wave as ``"add"``, a
+        one-departure wave as ``"remove"``, any other as ``"wave"``."""
+        return self._apply_wave(arrivals, departures, "apply_wave")
+
+    def _apply_wave(self, arrivals: Sequence, departures: Sequence[int],
+                    caller: str) -> WaveResult:
+        """Validate a wave's input and run it (``caller`` names the entry
+        point in errors)."""
+        self._check_churn_constraints(caller)
         arr: List[tuple] = []
         seen: set = set()
         for a in arrivals:
@@ -1081,7 +941,7 @@ class OnlineEmbedder:
                 raise ValueError(f"sid {sid} is already live")
             seen.add(sid)
             self._next_sid = max(self._next_sid, sid + 1)
-            arr.append((svc, int(sid), prio))
+            arr.append((svc, int(sid), prio, None))
         deps = [int(s) for s in departures]
         if len(deps) != len(set(deps)):
             raise ValueError("duplicate departure sid in wave")
@@ -1089,50 +949,93 @@ class OnlineEmbedder:
             if s not in self._sids:
                 raise KeyError(f"no live service {s}")
         wr = WaveResult(result=self._result,
-                        sids=[sid for _, sid, _ in arr], departed=deps)
-        pre_preempted = self.admission["preempted"]
-        if not arr and not deps:
-            return wr
-        if len(arr) + len(deps) == 1:
-            # deprecation parity: a size-1 wave IS the per-event path
-            if deps:
-                wr.result = self.remove(deps[0])
-            else:
-                svc, sid, prio = arr[0]
-                res = self.add(svc, sid=sid, priority=prio)
-                if res is not None:
-                    wr.result = res
-                    wr.admitted.append(sid)
-                else:
-                    wr.result = self._result
-                    if any(e[2] == sid for e in self._queue):
-                        wr.queued.append(sid)
-                    else:
-                        wr.rejected.append(sid)
-        else:
+                        sids=[a[1] for a in arr], departed=deps)
+        if arr or deps:
+            pre_preempted = self.admission["preempted"]
             self._wave(arr, deps, wr)
-        wr.n_preempted = self.admission["preempted"] - pre_preempted
+            wr.n_preempted = self.admission["preempted"] - pre_preempted
         return wr
 
+    def _wave_of_one(self, a: tuple) -> WaveResult:
+        """Run one arrival record as its own wave (queue and preemption
+        retries)."""
+        return self._wave([a], [], WaveResult(self._result, sids=[a[1]]))
+
+    def _move_rows(self, dep_rows: List[int], arr: Sequence[tuple]
+                   ) -> Tuple[List[tuple], Optional[power.PlacementState]]:
+        """The engine's row bookkeeping, shared by waves and faults: detach
+        the sorted ``dep_rows`` as ONE fused state update, drop their rows,
+        append the arrival records, rebuild the problem, and carry the
+        previous placement onto the new rows (``warm_state``; arrival rows
+        start unplaced).  Returns the dropped rows' ``(service, sid,
+        priority)`` in descending row order, and the warm state -- ``None``
+        when there was no placement to carry, or when no service is left
+        (the engine's state is then cleared)."""
+        tel = self.telemetry
+        state, prev_X, n0 = self._state, self._X, self.n_live
+        dropped: List[tuple] = []
+        if dep_rows:
+            with span(tel, "wave.detach"):
+                state = power.detach_vsrs(self._problem, state, dep_rows)
+                for r in sorted(dep_rows, reverse=True):
+                    dropped.append((self._vsrs.pop(r), self._sids.pop(r),
+                                    self._prio.pop(r)))
+                    self._drop_row(r)
+        with span(tel, "wave.rebuild"):
+            for svc, sid, prio, _ in arr:
+                self._vsrs.append(svc)
+                self._sids.append(sid)
+                self._prio.append(prio)
+                self._batch_cache = (svc if self._batch_cache is None
+                                     else self._batch_cache.concat(svc))
+            if self._vsrs:
+                self._rebuild_problem()
+        if not self._vsrs:
+            self._problem = self._X = self._state = self._result = None
+            self._batch_cache = None
+        if not self._vsrs or prev_X is None:
+            return dropped, None
+        surv = sorted(set(range(n0)) - set(dep_rows))
+        row_map = surv + [-1] * (self._problem.R - len(surv))
+        with span(tel, "wave.warm_state"):
+            st = power.warm_state(
+                self._problem, prev_X,
+                prev_loads=(state.omega, state.tm, state.theta, state.lam),
+                row_map=row_map)
+        return dropped, st
+
     def _wave(self, arr: List[tuple], deps: List[int], wr: WaveResult,
-              deferred: Optional[List[tuple]] = None) -> WaveResult:
-        """One attempt at a batched wave; admission refusals roll the whole
-        attempt back and recurse without the refused arrivals."""
+              deferred: Optional[List[tuple]] = None,
+              drain: bool = True) -> WaveResult:
+        """One attempt at a wave; admission refusals roll the whole attempt
+        back and recurse without the refused arrivals.
+
+        Arrival records are ``(service, sid, priority, qseq)``; ``qseq`` is
+        the queue position of a parked arrival's re-attempt (``None`` when
+        fresh).  A re-attempt that is refused, or whose source is down,
+        goes back to that position and is not counted again as rejected,
+        queued or stranded.  ``drain=False`` leaves the rejection queue
+        alone after the wave's departures (a preemption victim's)."""
         deferred = [] if deferred is None else deferred
+        event = ("add" if len(wr.sids) == 1 and not wr.departed
+                 else "remove" if len(wr.departed) == 1 and not wr.sids
+                 else "wave")
         # source-down arrivals park immediately: a fault is not an SLA
         # rejection (recursive attempts see only the already-filtered list)
         h = self.spec.health
         if h is not None and arr:
             up = []
-            for svc, sid, prio in arr:
+            for a in arr:
+                svc, sid, prio, qseq = a
                 if bool(h.node_up[int(svc.src[0])]):
-                    up.append((svc, sid, prio))
+                    up.append(a)
                     continue
-                self._park(svc, sid, prio)
-                self.admission["queued"] += 1
-                if self.monitor is not None:
-                    self.monitor.strand(sid, self._now,
-                                        detail=f"sid={sid} source down")
+                self._park(svc, sid, prio, seq=qseq)
+                if qseq is None:
+                    self.admission["queued"] += 1
+                    if self.monitor is not None:
+                        self.monitor.strand(sid, self._now,
+                                            detail=f"sid={sid} source down")
                 self.stats.append(OnlineStats(
                     event="strand", method="fault",
                     objective=self.objective(), power_w=self.power_w(),
@@ -1146,115 +1049,54 @@ class OnlineEmbedder:
         prev = (self._vsrs[:], self._sids[:], self._prio[:],
                 self._batch_cache, self._problem, self._X, self._state,
                 self._result, self._events_since_defrag)
-        state, prev_X = self._state, self._X
-        n0 = self.n_live
-        # phase 1: departures detach as ONE fused state update, BEFORE any
-        # arrival lands (merge_timelines tie order; capacity is never
-        # double-counted inside a wave)
+        # phase 1-2: departures detach BEFORE any arrival lands
+        # (merge_timelines tie order; capacity is never double-counted
+        # inside a wave), then arrivals join the batch in one pass
         dep_rows = sorted(self._sids.index(s) for s in deps)
-        if dep_rows:
-            with span(tel, "wave.detach"):
-                state = power.detach_vsrs(self._problem, state, dep_rows)
-                for r in sorted(dep_rows, reverse=True):
-                    del self._vsrs[r]
-                    del self._sids[r]
-                    del self._prio[r]
-                    self._drop_row(r)
-        surv = [i for i in range(n0) if i not in set(dep_rows)]
-        # phase 2: arrivals join the batch in one pass
-        with span(tel, "wave.rebuild"):
-            for svc, sid, prio in arr:
-                self._vsrs.append(svc)
-                self._sids.append(sid)
-                self._prio.append(prio)
-                self._batch_cache = (svc if self._batch_cache is None
-                                     else self._batch_cache.concat(svc))
-            if self._vsrs:
-                self._rebuild_problem()
+        _, st = self._move_rows(dep_rows, arr)
         if not self._vsrs:
-            self._problem = self._X = self._state = self._result = None
-            self._batch_cache = None
-            self.stats.append(OnlineStats("wave", "empty", 0.0, 0.0, 0))
+            self.stats.append(OnlineStats(event, "empty", 0.0, 0.0, 0))
             wr.result = None
-            with span(tel, "wave.drain"):
-                self._drain_queue()
+            if drain:
+                with span(tel, "wave.drain"):
+                    self._drain_queue()
             return self._wave_deferred(wr, deferred)
         self._events_since_defrag += len(arr) + len(dep_rows)
-        new_rows = list(range(len(surv), self.n_live))
-        row_map = surv + [-1] * (self._problem.R - len(surv))
-        with span(tel, "wave.warm_state"):
-            if prev_X is None:
-                # cold wave: start every arrival at its pinned source (the
-                # targeted sweeps re-place them; mirrors
-                # add-under-admission)
+        new_rows = list(range(self.n_live - len(arr), self.n_live))
+        if st is None and not self._admission_active:
+            # empty engine and nothing to admit against: one full solve
+            with span(tel, "wave.full_solve"):
+                res = self._repack()
+        else:
+            if st is None:
+                # cold wave under admission control: every arrival starts
+                # at its pinned source, so the masked re-solve and the
+                # budget test below still apply
                 st = power.init_state(self._problem,
                                       np.asarray(self._problem.fixed_node))
-                prev_power, prev_viol = 0.0, 0.0
-            else:
-                st = power.warm_state(
-                    self._problem, prev_X,
-                    prev_loads=(state.omega, state.tm, state.theta,
-                                state.lam),
-                    row_map=row_map)
-                prev_power = 0.0 if prev[7] is None else prev[7].power
-                prev_viol = (0.0 if prev[7] is None
-                             else float(prev[7].breakdown.violation))
-        # phase 3: ONE batched re-solve for the whole wave
-        kw = self._add_kw if new_rows else self._remove_kw
-        wave_bucket = 0
-        if tel is not None and new_rows:
-            n_pos = int((~np.asarray(
-                self._problem.fixed_mask)[new_rows]).sum())
-            wave_bucket = solvers._pow2(n_pos) if n_pos else 0
-        with span(tel, "resolve_wave", n_arrive=len(new_rows),
-                  n_depart=len(deps), wave_bucket=wave_bucket,
-                  r_bucket=int(self._problem.R)) as sp:
-            res = solvers.resolve_wave(
-                self._problem, st, new_rows, key=self._split_key(),
-                spec=self.spec, **self._resolve_kw(kw))
-            if tel is not None:
-                # _result already materialized res.X/breakdown on host, so
-                # this reads no device value
-                sp.attrs["objective"] = float(res.objective)
-        # phase 4: admission, per arrival in priority order
-        if new_rows and self._admission_active:
-            with span(tel, "wave.admission"):
-                refused = self._wave_refusals(res, arr, new_rows,
-                                              prev_power, prev_viol)
-            if refused:
-                (self._vsrs, self._sids, self._prio, self._batch_cache,
-                 self._problem, self._X, self._state, self._result,
-                 self._events_since_defrag) = prev
-                keep = []
-                for i, (svc, sid, prio) in enumerate(arr):
-                    if i not in refused:
-                        keep.append((svc, sid, prio))
-                        continue
-                    reason = refused[i]
-                    if (reason == "power_budget_exceeded"
-                            and self.spec.preempt):
-                        # retried per-event after the wave commits, where
-                        # preemption may park a lower-class victim
-                        deferred.append((svc, sid, prio))
-                        continue
-                    self.admission["rejected"] += 1
-                    if self.monitor is not None:
-                        self.monitor.count("admission_rejected",
-                                           detail=f"sid={sid}")
-                        self.monitor.count(reason, detail=f"sid={sid}")
-                    if self.queue_rejected:
-                        self.admission["queued"] += 1
-                        self._park(svc, sid, prio)
-                        wr.queued.append(sid)
-                    else:
-                        wr.rejected.append(sid)
-                    self.stats.append(OnlineStats(
-                        event="reject", method="admission",
-                        objective=res.objective, power_w=res.power,
-                        n_live=self.n_live))
-                return self._wave(keep, deps, wr, deferred)
+            res = self._wave_solve(st, new_rows, len(deps))
+            # phase 4: admission, per arrival in priority order
+            if new_rows and self._admission_active:
+                with span(tel, "wave.admission"):
+                    refused = self._wave_refusals(res, arr, new_rows, prev[7])
+                if refused:
+                    (self._vsrs, self._sids, self._prio, self._batch_cache,
+                     self._problem, self._X, self._state, self._result,
+                     self._events_since_defrag) = prev
+                    (i, reason), = refused.items()
+                    keep = arr[:i] + arr[i + 1:]
+                    if reason == "power_budget_exceeded" and self.spec.preempt:
+                        if keep or deps:
+                            # retried as a wave of one after this one commits
+                            deferred.append(arr[i])
+                            return self._wave(keep, deps, wr, deferred, drain)
+                        # a wave of one: park a lower-class victim and retry
+                        if self._preempt_victim(arr[i][2]) is not None:
+                            return self._wave(arr, deps, wr, deferred, drain)
+                    self._refuse(arr[i], reason, res, wr)
+                    return self._wave(keep, deps, wr, deferred, drain)
         # phase 5: commit, then drain freed capacity into queued arrivals
-        for _, sid, _ in arr:
+        for _, sid, _, _ in arr:
             wr.admitted.append(sid)
             self.admission["admitted"] += 1
             if self.monitor is not None:
@@ -1263,40 +1105,86 @@ class OnlineEmbedder:
             with span(tel, "wave.full_solve"):
                 res = self._repack(incumbent=res)
         with span(tel, "wave.commit"):
-            self._commit(res, "wave")
+            self._commit(res, event)
         wr.result = res
-        if deps:
+        if deps and drain:
             with span(tel, "wave.drain"):
                 self._drain_queue()
         return self._wave_deferred(wr, deferred)
 
+    def _wave_solve(self, st: power.PlacementState, new_rows: List[int],
+                    n_depart: int) -> solvers.SolveResult:
+        """Phase 3: ONE batched re-solve for the whole wave."""
+        tel = self.telemetry
+        kw = self._add_kw if new_rows else self._remove_kw
+        wave_bucket = 0
+        if tel is not None and new_rows:
+            n_pos = int((~np.asarray(
+                self._problem.fixed_mask)[new_rows]).sum())
+            wave_bucket = solvers._pow2(n_pos) if n_pos else 0
+        with span(tel, "resolve_wave", n_arrive=len(new_rows),
+                  n_depart=n_depart, wave_bucket=wave_bucket,
+                  r_bucket=int(self._problem.R)) as sp:
+            res = solvers.resolve_wave(
+                self._problem, st, new_rows, key=self._split_key(),
+                spec=self.spec, **self._resolve_kw(kw))
+            if tel is not None:
+                # _result already materialized res.X/breakdown on host, so
+                # this reads no device value
+                sp.attrs["objective"] = float(res.objective)
+        return res
+
+    def _refuse(self, a: tuple, reason: str, res: solvers.SolveResult,
+                wr: WaveResult) -> None:
+        """Record one arrival record's admission refusal: reject it, or
+        park it with ``queue_rejected`` (a queue re-attempt always goes back
+        to its old position and is not counted again)."""
+        svc, sid, prio, qseq = a
+        if qseq is None:
+            self.admission["rejected"] += 1
+            if self.monitor is not None:
+                self.monitor.count("admission_rejected", detail=f"sid={sid}")
+                self.monitor.count(reason, detail=f"sid={sid}")
+            if self.queue_rejected:
+                self.admission["queued"] += 1
+        if self.queue_rejected or qseq is not None:
+            self._park(svc, sid, prio, seq=qseq)
+            wr.queued.append(sid)
+        else:
+            wr.rejected.append(sid)
+        self.stats.append(OnlineStats(
+            event="reject", method="admission", objective=res.objective,
+            power_w=res.power, n_live=self.n_live))
+
     def _wave_deferred(self, wr: WaveResult,
                        deferred: List[tuple]) -> WaveResult:
-        """Retry power-refused arrivals per-event (``spec.preempt``: each
-        may park a lower-class victim to free budget)."""
-        for svc, sid, prio in deferred:
-            res = self.add(svc, sid=sid, priority=prio)
-            if res is not None:
-                wr.admitted.append(sid)
-                wr.result = res
-            elif any(e[2] == sid for e in self._queue):
-                wr.queued.append(sid)
-            else:
-                wr.rejected.append(sid)
+        """Retry power-refused arrivals (``spec.preempt``) after the wave
+        commits, each as a wave of one, which may park a lower-class
+        victim to free budget."""
+        for a in deferred:
+            one = self._wave_of_one(a)
+            wr.admitted += one.admitted
+            wr.rejected += one.rejected
+            wr.queued += one.queued
+            wr.result = one.result
         return wr
 
     def _wave_refusals(self, res: solvers.SolveResult, arr: List[tuple],
-                       new_rows: List[int], prev_power: float,
-                       prev_viol: float) -> Dict[int, str]:
-        """Admission verdicts for one solved wave attempt: {arr index ->
-        reason}.  The wave's budgets are the per-event budgets linearly
-        extended to the wave (mean marginal power / violation increase per
-        arrival); when exceeded, ONE victim is refused per attempt --
-        lowest priority class first, and within it the arrival with the
-        highest exact attributed watts (``power.attribute_power``) when a
-        power budget is set, else the newest -- and the remaining wave is
-        re-solved, so higher classes keep their seats."""
+                       new_rows: List[int],
+                       prev: Optional[solvers.SolveResult]
+                       ) -> Dict[int, str]:
+        """Admission verdicts for one solved wave attempt against the
+        engine's ``prev`` result: {arr index -> reason}.  The wave's
+        budgets are the per-arrival budgets linearly extended to the wave
+        (mean marginal power / violation increase per arrival); when
+        exceeded, ONE victim is refused per attempt -- lowest priority
+        class first, and within it the arrival with the highest exact
+        attributed watts (``power.attribute_power``) when a power budget is
+        set, else the newest -- and the remaining wave is re-solved, so
+        higher classes keep their seats."""
         budget, tol = self.admit_power_budget_w, self.admit_violation_tol
+        prev_power = 0.0 if prev is None else prev.power
+        prev_viol = 0.0 if prev is None else float(prev.breakdown.violation)
         over_power = (budget is not None
                       and res.power - prev_power > budget * len(new_rows))
         over_viol = (tol is not None
@@ -1306,9 +1194,9 @@ class OnlineEmbedder:
             return {}
         reason = ("power_budget_exceeded" if over_power
                   else "violation_budget_exceeded")
-        lowest = max(prio for _, _, prio in arr)
+        lowest = max(a[2] for a in arr)
         cls = [j for j in range(len(arr)) if arr[j][2] == lowest]
-        if budget is not None:
+        if budget is not None and len(cls) > 1:
             per = power.attribute_power(self._problem, np.asarray(res.X),
                                         res.breakdown, n_rows=self.n_live)
             i = max(cls, key=lambda j: (float(per[new_rows[j]]), j))
@@ -1317,16 +1205,14 @@ class OnlineEmbedder:
         return {i: reason}
 
     def _drain_queue(self) -> None:
-        """Retry parked arrivals class-by-class (FIFO within a class);
-        stop at the first re-rejection.  Runs after EVERY
-        capacity-increasing event: departures (per-event or wave),
-        node/link recoveries, and brownout_end."""
+        """Retry parked arrivals class-by-class (FIFO within a class), each
+        as a wave of one; stop at the first re-refusal.  Runs after EVERY
+        capacity-increasing event: departures, node/link recoveries, and
+        brownout_end."""
         while self._queue:
             prio, seq, sid, service = heapq.heappop(self._queue)
-            if self.add(service, sid=sid, priority=prio, _retry=True,
-                        _qseq=seq) is None:
-                # add() re-parked it at its original position (seq)
-                break
+            if not self._wave_of_one((service, sid, prio, seq)).admitted:
+                break  # re-parked at its original position (seq)
 
     def cancel_queued(self, sid: int) -> bool:
         """Drop a parked arrival (its lifetime ended while queued)."""
@@ -1450,36 +1336,17 @@ class OnlineEmbedder:
             return None  # nothing placed; _rebuild_problem degrades later
         recovery = event.startswith("recover")
         stranded, moved = ([], []) if recovery else self._fault_rows()
-        state = self._state
-        prev_X = self._X
-        n0 = self.n_live
-        if stranded:
-            state = power.detach_vsrs(self._problem, state, stranded)
-            for r in sorted(stranded, reverse=True):
-                svc, sid = self._vsrs[r], self._sids[r]
-                self._park(svc, sid, self._prio[r])
-                if self.monitor is not None:
-                    self.monitor.strand(sid, self._now,
-                                        detail=f"sid={sid} {event}")
-                del self._vsrs[r]
-                del self._sids[r]
-                del self._prio[r]
-                self._drop_row(r)
-        if not self._vsrs:
-            self._problem = self._X = self._state = self._result = None
-            self._batch_cache = None
+        moved_new = [r - sum(s < r for s in stranded) for r in moved]
+        dropped, st = self._move_rows(stranded, ())
+        for svc, sid, prio in dropped:
+            self._park(svc, sid, prio)
+            if self.monitor is not None:
+                self.monitor.strand(sid, self._now,
+                                    detail=f"sid={sid} {event}")
+        if st is None:
             self.stats.append(OnlineStats(event, "empty", 0.0, 0.0, 0))
             return None
-        dead = set(stranded)
-        surv = [i for i in range(n0) if i not in dead]
-        moved_new = [surv.index(r) for r in moved]
-        self._rebuild_problem()
         self._events_since_defrag += 1
-        row_map = surv + [-1] * (self._problem.R - len(surv))
-        st = power.warm_state(
-            self._problem, prev_X,
-            prev_loads=(state.omega, state.tm, state.theta, state.lam),
-            row_map=row_map)
         if not recovery and not moved_new and not stranded:
             # the dead element hosted nothing: re-score the same placement
             # on the degraded problem, no solver work

@@ -7,11 +7,11 @@ scheduler drives a ``repro.api.CFNSession`` -- or, passed via
 schedules onto a federated fog unchanged -- whose declarative
 ``PlacementSpec`` carries the constraint set (SLA hop bounds, admission
 power budget, regional budgets) and the portfolio configuration.  ``add_service`` /
-``remove_service`` are churn events on the session: the previous embedding
-is carried through ``power.warm_state`` and only the churned service's VMs
-are re-placed by ``solvers.resolve_incremental`` -- a periodic
-full-portfolio defrag (masked by the same spec) bounds the drift of local
-re-optimization.  Per-service ``Placement.power_w`` is attributed from the
+``remove_service`` are churn events on the session, each a wave of one:
+the previous embedding is carried through ``power.warm_state`` and only
+the churned service's VMs are re-placed by ``solvers.resolve_wave`` -- a
+periodic full-portfolio defrag (masked by the same spec) bounds the drift
+of local re-optimization.  Per-service ``Placement.power_w`` is attributed from the
 per-node breakdown via each service's placed nodes and traversed routes
 (``CFNSession.attribute``), so tenant numbers sum to the fleet total.
 """

@@ -330,7 +330,7 @@ def test_online_embedder_event_loop(topo):
 
     eng.add(_services(1, seed0=900)[0])
     assert eng.n_live == 5
-    assert eng.result.method == "incremental"
+    assert eng.result.method == "wave"
     # engine state agrees with a fresh evaluation of its placement
     fresh = power.init_state(eng.problem, jnp.asarray(eng.X))
     assert abs(eng.objective() - float(fresh.obj)) <= \

@@ -282,6 +282,40 @@ def test_full_solve_wave_spans_portfolio_phases(wave_trace):
     assert full[1] <= commit[0]
 
 
+def test_single_events_run_the_wave_path(topo, tmp_path):
+    """``add`` and ``remove`` are waves of one: each holds exactly one
+    ``resolve_wave`` and the wave's phases, in order, and neither is
+    counted as a public ``apply_wave`` call."""
+    tel = Telemetry(convergence=False)
+    sess = CFNSession(topo, PlacementSpec(effort="quick", defrag_every=0),
+                      telemetry=tel)
+    sess.solve(vsr.random_vsrs(4, rng=np.random.default_rng(5), n_vms=3))
+
+    def add_remove():
+        sess.add(_svc(40), sid=40)
+        sess.remove(sess.sids[0])
+
+    spans = _profiled(tmp_path, add_remove)
+    assert "repro.apply_wave" not in spans
+    assert len(spans["repro.resolve_wave"]) == 2
+    phases = {"repro.add": ["repro.wave.rebuild", "repro.wave.warm_state",
+                            "repro.resolve_wave", "repro.wave.commit"],
+              "repro.remove": ["repro.wave.detach", "repro.wave.rebuild",
+                               "repro.wave.warm_state", "repro.resolve_wave",
+                               "repro.wave.commit", "repro.wave.drain"]}
+    for event, order in phases.items():
+        (a, b), = spans[event]
+        inside = [[s for s in spans.get(n, []) if a <= s[0] and s[1] <= b]
+                  for n in order]
+        assert all(len(s) == 1 for s in inside), (event, inside)
+        ends = [s[0] for s in inside]
+        assert all(e0[1] <= e1[0] for e0, e1 in zip(ends, ends[1:]))
+    assert [s.event for s in sess.stats[-2:]] == ["add", "remove"]
+    assert [s.method for s in sess.stats[-2:]] == ["wave", "wave"]
+    solves = [e for e in tel.events if e["type"] == "solve"]
+    assert [e["event"] for e in solves[-2:]] == ["add", "remove"]
+
+
 ANNEAL_SPANS = ("repro.resolve.anneal", "repro.portfolio.anneal")
 
 
